@@ -25,7 +25,8 @@ import sys
 import traceback
 
 
-def _run_csv() -> None:
+def _run_csv() -> int:
+    """Print the CSV sweep; returns how many suites raised."""
     print("name,us_per_call,derived")
     from benchmarks import (  # noqa: E402
         bench_cnn_serving,
@@ -42,16 +43,18 @@ def _run_csv() -> None:
         ("serving", bench_serving.run),
         ("cnn_serving", bench_cnn_serving.run),
     ]
+    failed = 0
     for name, fn in suites:
         try:
             for row in fn():
                 print(f"{row['bench']},{row['us_per_call']:.1f},"
                       f"\"{row['derived']}\"", flush=True)
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — run the rest, then fail
+            failed += 1
             print(f"{name},ERROR,\"{type(e).__name__}: {e}\"", flush=True)
             traceback.print_exc(file=sys.stderr)
 
-    # roofline rows (dry-run artifacts; baseline table lives in EXPERIMENTS.md)
+    # roofline rows (dry-run artifacts under results/dryrun/)
     try:
         from pathlib import Path
 
@@ -65,8 +68,11 @@ def _run_csv() -> None:
                   f"{max(r['compute_s'], r['memory_s'], r['collective_s'])*1e6:.1f},"
                   f"\"dominant={r['dominant']} useful={r['useful_ratio']:.2f}"
                   f" fits={r['fits_16gb']}\"", flush=True)
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline,SKIPPED,\"{e}\"", flush=True)
+    except Exception as e:  # noqa: BLE001 — run the rest, then fail
+        failed += 1
+        print(f"roofline,ERROR,\"{type(e).__name__}: {e}\"", flush=True)
+        traceback.print_exc(file=sys.stderr)
+    return failed
 
 
 def _run_json(nets, out_path: str, batch: int, iters: int,
@@ -119,14 +125,17 @@ def main(argv=None) -> None:
     ap.add_argument("--no-serving", action="store_true",
                     help="skip the batched-serving rows on the json path")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+
+    compile_cache.enable()
     if args.json:
         serving_batches = () if args.no_serving else tuple(
             int(b) for b in args.serving_batches.split(",") if b.strip())
         _run_json(tuple(n.strip() for n in args.nets.split(",") if n.strip()),
                   args.out, args.batch, args.iters,
                   serving_batches, args.serving_requests)
-    else:
-        _run_csv()
+    elif _run_csv():
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -11,7 +11,7 @@ Two passes, both pure Python (no kernel execution, no tracing):
   default — runs it on every compiled plan.
 * ``repro.analysis.lint`` — the **repo lint**: AST rules enforcing the
   repo's kernel/engine invariants (``pallas_call`` kwargs threading,
-  knob-mutation cache invalidation, resolver-owned ``Unblocked`` index
+  knob-mutation cache invalidation, resolver-owned ``pl.Element`` index
   maps, no silent excepts, no magic-number budgets).
 
 See ``repro/analysis/README.md`` for the rule taxonomy and CLI usage

@@ -60,7 +60,7 @@ RULES = {
              "bucket caches (knob name mismatch, _KnobDict mutator not "
              "calling _on_change, or clear_caches missing a cache)"),
     "R003": ("lint",
-             "pl.Unblocked index maps must use resolver-named offsets — "
+             "pl.Element index maps must use resolver-named offsets — "
              "no inline numeric arithmetic (literal 0 excepted)"),
     "R004": ("lint",
              "silent exception handler: bare/broad except whose body is "
@@ -101,8 +101,8 @@ RULES = {
              "the two redundant derivations is wrong"),
     "K106": ("sanitizer",
              "VMEM scratch carry discipline violated: the carried grid "
-             "axis is not 'arbitrary', the scratch ref is overwritten "
-             "before its carried rows are consumed, or the store is not "
+             "axis is not 'arbitrary', the carried scratch ref is never "
+             "refreshed, or the store is not "
              "the tail row-slice of the fresh band (stale rows would be "
              "re-consumed by the next band step)"),
 }

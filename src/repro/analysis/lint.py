@@ -12,11 +12,11 @@ Rules (see ``findings.RULES`` / ``analysis/README.md``):
   ``name = _knob("name")`` descriptors must name the attribute they
   wrap, and ``clear_caches`` must clear every cache dict ``__init__``
   creates.
-* **R003** — ``pl.BlockSpec(..., indexing_mode=pl.Unblocked())`` index
-  maps may only scale grid indices by *named* offsets (``row_step``,
-  ``in_step``, …) that come from the geometry resolvers; inline numeric
-  arithmetic (any literal other than a standalone ``0``) hides band
-  math the verifier cannot see.
+* **R003** — a ``pl.BlockSpec`` with a ``pl.Element(...)`` block dim
+  (element-offset indexing) may only scale grid indices by *named*
+  offsets (``row_step``, ``in_step``, …) that come from the geometry
+  resolvers; inline numeric arithmetic (any literal other than a
+  standalone ``0``) hides band math the verifier cannot see.
 * **R004** — no silent handlers: a bare/broad ``except`` whose body is
   only ``pass``/``...`` swallows planner and IO failures.
 * **R005** — byte budgets appear in comparisons only through the named
@@ -181,15 +181,14 @@ def _r002(tree: ast.AST, path: str) -> List[Finding]:
 
 # -- R003 -------------------------------------------------------------------
 
-def _has_unblocked(call: ast.Call) -> bool:
-    for kw in call.keywords:
-        if kw.arg == "indexing_mode":
-            for n in ast.walk(kw.value):
-                if isinstance(n, ast.Attribute) and n.attr == "Unblocked":
-                    return True
-                if isinstance(n, ast.Name) and n.id == "Unblocked":
-                    return True
-    return False
+def _has_element_dim(call: ast.Call) -> bool:
+    """True when the spec's block shape declares a ``pl.Element`` dim."""
+    shape = call.args[0] if call.args else next(
+        (kw.value for kw in call.keywords if kw.arg == "block_shape"), None)
+    if shape is None:
+        return False
+    return any(isinstance(n, ast.Call) and _call_name(n) == "Element"
+               for n in ast.walk(shape))
 
 
 def _r003(tree: ast.AST, path: str) -> List[Finding]:
@@ -197,7 +196,7 @@ def _r003(tree: ast.AST, path: str) -> List[Finding]:
     for node in ast.walk(tree):
         if not (isinstance(node, ast.Call)
                 and _call_name(node) == "BlockSpec"
-                and _has_unblocked(node)):
+                and _has_element_dim(node)):
             continue
         lam = next((a for a in node.args if isinstance(a, ast.Lambda)), None)
         if lam is None:
@@ -212,7 +211,8 @@ def _r003(tree: ast.AST, path: str) -> List[Finding]:
                     and n.value != 0):
                 out.append(Finding(
                     "error", _loc(path, node), "R003",
-                    f"Unblocked index map uses inline literal {n.value!r}; "
+                    f"element-offset index map uses inline literal "
+                    f"{n.value!r}; "
                     f"offsets must come from a geometry resolver name"))
                 break
     return out

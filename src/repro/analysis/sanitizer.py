@@ -220,9 +220,13 @@ def _a_chain_cell(blk, h, w, c, chain, ocs, pool, im2col=True, itemsize=4,
     in_rows, in_w = band, w + 2 * chain[0][5]
     for i, ((kh, kw, sy, sx, py, px), (oh, ow, ci, oc)) in enumerate(
             zip(chain, dims)):
+        # a weight block that moves along the oc grid is double-buffered;
+        # the grid-invariant ones are fetched once
+        copies = 1
         if i == last and oc_block_final is not None:
+            copies = 2 if oc_block_final < oc else 1
             oc = min(oc_block_final, oc)
-        weights += kh * kw * ci * oc
+        weights += copies * kh * kw * ci * oc
         patch_c = kh * kw * ci if im2col else ci
         stage_peak = max(stage_peak, in_rows * in_w * ci
                          + m[i] * ow * patch_c + m[i] * ow * oc)
@@ -588,13 +592,14 @@ class Ref:
 
 
 class DS:
-    """``pl.ds(start, size)`` — a (possibly affine) dynamic slice."""
+    """``pl.ds(start, size, stride)``: ``size`` elements from ``start``,
+    ``stride`` apart."""
+    __slots__ = ("start", "size", "stride")
 
-    __slots__ = ("start", "size")
-
-    def __init__(self, start, size):
+    def __init__(self, start, size, stride=1):
         self.start = start
         self.size = size
+        self.stride = stride
 
 
 class Closure:
@@ -626,13 +631,26 @@ class PartialV:
         self.kwargs = kwargs
 
 
-class BlockSpecV:
-    __slots__ = ("block_shape", "index_map", "unblocked")
+class ElementV:
+    """A ``pl.Element(size)`` block dim: the index map returns an element
+    offset on that axis instead of a block index."""
+    __slots__ = ("size",)
 
-    def __init__(self, block_shape, index_map, unblocked):
-        self.block_shape = block_shape
+    def __init__(self, size):
+        self.size = size
+
+
+class BlockSpecV:
+    """``block_shape`` holds plain sizes (``None`` = squeezed);
+    ``element_axes`` are the axes declared ``pl.Element``."""
+    __slots__ = ("block_shape", "index_map", "element_axes")
+
+    def __init__(self, block_shape, index_map):
+        self.element_axes = frozenset(
+            a for a, d in enumerate(block_shape) if isinstance(d, ElementV))
+        self.block_shape = tuple(d.size if isinstance(d, ElementV) else d
+                                 for d in block_shape)
         self.index_map = index_map
-        self.unblocked = unblocked
 
 
 class ShapeDtypeV:
@@ -718,8 +736,6 @@ class _ModFn:
     def __init__(self, path):
         self.path = path
 
-
-_UNBLOCKED = object()
 
 _BUILTINS = {"range": range, "len": len, "min": min, "max": max,
              "enumerate": enumerate, "zip": zip, "tuple": tuple,
@@ -984,6 +1000,9 @@ class Interp:
                 return
         if mod == "repro.kernels.common":
             for alias in st.names:
+                if alias.name == "ACC_PRECISION":
+                    env.set(alias.asname or alias.name, "highest")
+                    continue
                 if alias.name != "ACC_DTYPE":
                     raise Unsupported(f"unknown common import {alias.name}")
                 env.set(alias.asname or alias.name, DtypeMarker("f32"))
@@ -1328,7 +1347,8 @@ class Interp:
                 self._axis_bounds(i, 1, dim, name, axis)
                 continue  # squeezed
             if isinstance(i, DS):
-                self._axis_bounds(i.start, i.size, dim, name, axis)
+                self._axis_bounds(i.start, (i.size - 1) * i.stride + 1, dim,
+                                  name, axis)
                 out.append(i.size)
                 continue
             if isinstance(i, slice):
@@ -1420,12 +1440,9 @@ class Interp:
                            list(kwargs["in_specs"]), out_specs,
                            kwargs["out_shape"], sem, scratch)
         if path == "pl.BlockSpec":
-            block_shape = tuple(args[0])
-            index_map = args[1]
-            mode = kwargs.get("indexing_mode")
-            return BlockSpecV(block_shape, index_map, mode is _UNBLOCKED)
-        if path == "pl.Unblocked":
-            return _UNBLOCKED
+            return BlockSpecV(tuple(args[0]), args[1])
+        if path == "pl.Element":
+            return ElementV(args[0])
         if path == "pl.program_id":
             axis = args[0]
             if axis not in self.sym_ranges:
@@ -1438,8 +1455,11 @@ class Interp:
                 raise Unsupported("pl.when on a non-affine predicate")
             return PlWhenV(pred)
         if path == "pl.ds":
-            return DS(args[0], args[1])
-        if path == "pltpu.TPUCompilerParams":
+            stride = kwargs.get("stride", args[2] if len(args) > 2 else 1)
+            if not isinstance(stride, int) or stride < 1:
+                raise Unsupported(f"pl.ds stride {stride!r}")
+            return DS(args[0], args[1], stride)
+        if path == "pltpu.CompilerParams":
             return CompilerParamsV(tuple(kwargs["dimension_semantics"]))
         if path == "pltpu.VMEM":
             return ShapeDtypeV(tuple(args[0]), _tag_of(args[1]))
@@ -1491,7 +1511,9 @@ class Interp:
                     self.finding("K100", "concatenate shape mismatch "
                                          f"{[s.shape for s in seq]}")
                 total += a.shape[axis]
-                joined = _arr_binop(joined, a, self)
+                # join dtype/taint metadata only: pieces may differ in
+                # width along the concatenation axis
+                joined = _arr_binop(joined, a.like(shape=joined.shape), self)
             base[axis] = total
             return joined.like(shape=tuple(base), mask=None)
         if name == "dot":
@@ -1621,7 +1643,7 @@ class Interp:
             for axis, (bd, comp) in enumerate(zip(spec.block_shape, idx)):
                 bsize = 1 if bd is None else bd
                 aff = Aff.lift(comp)
-                start = aff if spec.unblocked else aff * bsize
+                start = aff if axis in spec.element_axes else aff * bsize
                 lo, hi = start.bounds(self.sym_ranges)
                 if lo < 0 or hi + bsize > op.shape[axis]:
                     self.finding(
@@ -1635,8 +1657,8 @@ class Interp:
         if not isinstance(out_sds, ShapeDtypeV):
             raise Unsupported("out_shape is not a ShapeDtypeStruct")
         ospec = pv.out_specs
-        if ospec.unblocked:
-            raise Unsupported("unblocked output spec")
+        if ospec.element_axes:
+            raise Unsupported("element-offset output spec")
         oidx = self.eval_index_map(ospec.index_map, len(grid))
         used_syms = set()
         if len(oidx) != len(ospec.block_shape) or \
@@ -1813,15 +1835,26 @@ class Interp:
         """K106: a VMEM scratch carry must be consumed before overwrite,
         and the overwrite must keep the TAIL rows of the fresh band.
 
-        The carried axis is the innermost grid axis (scratch persists
-        across its steps), so it needs 'arbitrary' semantics: a parallel
-        or reordered axis would let a step read a carry its predecessor
-        has not produced yet.  Each step must (a) read the scratch
-        before writing it — the carried rows are this step's data, the
-        store is the NEXT step's — and (b) store exactly the last
-        ``scratch_rows`` rows of the fresh band (a provable tail
-        row-slice): a head slice or recomputed value would hand the next
-        band stale rows."""
+        A scratch ref whose first access in the body is a (full) store is
+        a staging buffer — the fused pool epilogue's conv band — and
+        carries nothing between steps; the discipline applies to the
+        refs read before they are written.  The carried axis is the
+        innermost grid axis (scratch persists across its steps), so it
+        needs 'arbitrary' semantics: a parallel or reordered axis would
+        let a step read a carry its predecessor has not produced yet.
+        Each step must store exactly the last ``scratch_rows`` rows of
+        the fresh band (a provable tail row-slice): a head slice or
+        recomputed value would hand the next band stale rows."""
+        carried = []
+        for ref in scratch_refs:
+            events = [e for e in self.scratch_events if e[1] is ref]
+            if not events:
+                self.finding("K106", f"scratch ref {ref.name} is never "
+                             "accessed — dead carry allocation")
+            elif events[0][0] == "load":
+                carried.append((ref, events))
+        if not carried:
+            return
         ca = len(grid) - 1
         sem = (dim_sem[ca] if dim_sem is not None and ca < len(dim_sem)
                else None)
@@ -1831,18 +1864,7 @@ class Interp:
                 f"carried grid axis g{ca} has dimension_semantics "
                 f"{sem!r} — scratch carry across steps requires "
                 "'arbitrary'")
-        for ref in scratch_refs:
-            events = [e for e in self.scratch_events if e[1] is ref]
-            if not events:
-                self.finding("K106", f"scratch ref {ref.name} is never "
-                             "accessed — dead carry allocation")
-                continue
-            if events[0][0] != "load":
-                self.finding(
-                    "K106",
-                    f"scratch ref {ref.name} is written before its "
-                    "carried rows are consumed — the carry from the "
-                    "previous band step is lost")
+        for ref, events in carried:
             stores = [e for e in events if e[0] == "store"]
             if not stores:
                 self.finding(

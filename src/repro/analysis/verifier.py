@@ -37,8 +37,8 @@ from repro.analysis.findings import (  # noqa: F401  (re-exported for
     PlanVerificationError,
 )
 from repro.core.fusion import (
+    ADVANCED_OC_BLOCK,
     IM2COL_METHODS,
-    _ADVANCED_OC_BLOCK,
     _conv_out_hw,
     _pool_out_hw,
     group_band_params,
@@ -171,7 +171,7 @@ def step_band_params(plan: ExecutionPlan,
         cp = -(-c // SUBLANES) * SUBLANES
         wp = w + 2 * spec.padding[1]
         im2col = step.method in IM2COL_METHODS
-        ocb = (min(_ADVANCED_OC_BLOCK[step.method], spec.out_channels)
+        ocb = (min(ADVANCED_OC_BLOCK, spec.out_channels)
                if im2col else spec.out_channels)
         blk = K.resolve_oh_block(oh, ow, wp, cp, kh, kw, sy, ocb,
                                  step.oh_block, im2col=im2col)

@@ -64,10 +64,10 @@ IM2COL_METHODS = frozenset({Method.ADVANCED_SIMD_4, Method.ADVANCED_SIMD_8})
 
 SUPPORTED_POOL_KINDS = frozenset({"max", "avg"})
 
-#: oc tile width each advanced method's fused kernel actually runs with
-#: (``conv2d_pool_fused`` maps the method to ``advanced_simd_4``/``_8``
-#: and ``conv2d.ops`` parses the block out of that name)
-_ADVANCED_OC_BLOCK = {Method.ADVANCED_SIMD_4: 4, Method.ADVANCED_SIMD_8: 8}
+#: oc tile width both advanced methods' kernels run with: ``conv2d.ops``
+#: raises the paper's 4/8 outputs per thread to the 128-lane MXU tile
+#: (``kernels.LANES``), the narrowest lane-dim block Mosaic accepts
+ADVANCED_OC_BLOCK = 128
 
 
 @dataclass(frozen=True)
@@ -154,12 +154,12 @@ def fused_working_set(conv: LayerSpec, pool: LayerSpec, method: Method,
     oc_halo = 0
     if lrn and im2col and lrn_n is not None:
         ocb, oc_halo = K.resolve_lrn_ocb(
-            oc, _ADVANCED_OC_BLOCK[method], (lrn_n, 1e-4, 0.75, 1.0),
+            oc, ADVANCED_OC_BLOCK, (lrn_n, 1e-4, 0.75, 1.0),
             None, ow, wp, c, kh, kw, sy, pool_t, im2col=im2col)
     elif lrn or not im2col:
         ocb = oc
     else:
-        ocb = min(_ADVANCED_OC_BLOCK[method], oc)
+        ocb = min(ADVANCED_OC_BLOCK, oc)
     return K.fused_cell_bytes(1, ow, wp, c, kh, kw, sy, ocb, pool_t,
                               im2col=im2col, oc_halo=oc_halo)
 
@@ -367,7 +367,7 @@ def _try_group(layers, i, method_for, no_fuse, fuse_relu, cin, h_in, w_in,
                 # chain rung: block the final stage's oc grid (its
                 # channels feed no further stage) before shortening the
                 # chain — incompatible with LRN, which is gone by here
-                oc_block_final = _ADVANCED_OC_BLOCK.get(method, 8)
+                oc_block_final = ADVANCED_OC_BLOCK
                 continue
             if len(convs) == 1:
                 return None  # single conv+pool whose floor cell busts
@@ -489,7 +489,7 @@ def group_band_params(group: FusedLayerSpec, method: Method,
             ocb, oc_halo = oc, 0  # basic_simd: always full oc width
         else:
             ocb, oc_halo = K.resolve_lrn_ocb(
-                oc, _ADVANCED_OC_BLOCK[method], lrn_t, lrn_oc_block, ow,
+                oc, ADVANCED_OC_BLOCK, lrn_t, lrn_oc_block, ow,
                 wp, cp, kh, kw, sy, pool_t)
         pkh, _, psy, _ = pool_t
         ph = (oh - pkh) // psy + 1

@@ -89,6 +89,11 @@ def infer_param_shapes(net: NetworkDef) -> Dict[str, Tuple]:
 SIMD_METHODS: Tuple[Method, ...] = tuple(
     m for m in Method if m in FUSABLE_METHODS)
 
+#: ladder methods with no Pallas kernel Mosaic compiles: the §4.2 kernel
+#: keeps channels off the lane axis, and broadcasting its per-channel
+#: weights over the spatial map needs a vector reshape Mosaic lacks
+NO_TPU_KERNEL: Tuple[Method, ...] = (Method.BASIC_PARALLEL,)
+
 #: default per-layer band-override candidates the autotuner tries on top
 #: of the resolver's auto sizing (clipped per layer to its output height)
 OH_BLOCK_CANDIDATES: Tuple[int, ...] = (4, 8, 16, 32, 64)
@@ -482,6 +487,13 @@ def compile_plan(net: NetworkDef, *,
                                   spec=spec))
         else:
             raise ValueError(spec.kind)
+    if use_pallas and jax.default_backend() == "tpu":
+        refused = sorted({s.method.value for s in steps
+                          if s.kind == "conv" and s.method in NO_TPU_KERNEL})
+        if refused:
+            raise NotImplementedError(
+                f"{net.name}: no TPU Pallas kernel for method(s) {refused}; "
+                "pick another method or use_pallas=False")
     plan = ExecutionPlan(net=net, fuse=fuse, use_pallas=use_pallas,
                          steps=tuple(steps), items=tuple(final_items),
                          vmem_budget=vmem_budget)

@@ -6,12 +6,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import interpret_default
 from repro.kernels.attention.kernel import flash_attention_pallas
 from repro.kernels.attention.ref import flash_attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "attn_softcap",
@@ -19,7 +16,7 @@ def _on_tpu() -> bool:
 def flash_attention(q, k, v, *, causal=True, window=0, attn_softcap=0.0,
                     scale=None, interpret: bool = None):
     """q: [b, sq, h, hd]; k/v: [b, skv, kvh, hd] (kv heads auto-expanded)."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_default() if interpret is None else interpret
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k = jnp.repeat(k, group, axis=2)

@@ -22,7 +22,7 @@ Spatial tiling (the ``oh_block`` knob): both SIMD kernels split the output
 height into bands of ``oh_block`` rows.  Each grid cell loads only the
 input-row band its output band needs — ``(oh_block-1)*stride + KH`` rows
 including the halo, addressed stride-aware with an element-offset
-(``pl.Unblocked``) BlockSpec so neighbouring bands may overlap by the
+(``pl.Element``) block dim so neighbouring bands may overlap by the
 ``KH - stride`` halo rows.  ``oh_block=None`` picks the largest band whose
 working set (input band + im2col patches + weights + output block) fits
 ``VMEM_BUDGET_BYTES`` — so frames far larger than VMEM (e.g. 512×512×64)
@@ -30,8 +30,9 @@ run on the same ladder instead of trying to stage the whole padded frame.
 
 VMEM budget: block shapes keep the minor dimension lane-aligned when the
 channel count allows (ops.py pads channels — the paper's divisible-by-4
-observation at lane width 128/8); the heuristic targets half of the ~16 MB
-per-core VMEM to leave room for double buffering.
+observation at lane width 128/8); the heuristic targets half of Mosaic's
+default 16 MiB scoped VMEM to leave room for double buffering, and the
+cells request ``VMEM_LIMIT_BYTES`` for what the model does not charge.
 
 Fused pooling epilogue (super-layers): both SIMD kernels accept an
 optional ``pool=(pkh, pkw, psy, psx, kind, pool_relu)``.  A grid cell then
@@ -100,7 +101,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import ACC_DTYPE
+from repro.kernels.common import ACC_DTYPE, ACC_PRECISION
 
 # Target working set per grid cell — half the ~16 MB/core VMEM, leaving the
 # other half for the pipeline's double buffering.
@@ -108,12 +109,26 @@ VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 # Chain cells keep every stage's full-width weights resident (stage N+1
 # consumes every output channel of stage N, so there is no oc tile to
-# shrink them).  Weights are grid-invariant — fetched once, never
-# double-buffered — so the chain check runs against near-full VMEM
-# capacity (16 MB minus pipeline headroom) instead of the half-capacity
-# streaming budget; the streamed input/output bands are charged on top of
-# the per-stage live set, standing in for their double buffers.
+# shrink them).  Weights are grid-invariant whole-array blocks, which
+# Mosaic single-buffers (its lowering forces a synchronous pipeline for
+# a trivial window), so the chain check runs against near-full default
+# scoped VMEM (16 MiB minus pipeline headroom) instead of the
+# half-capacity streaming budget; the streamed input/output bands are
+# charged on top of the per-stage live set, standing in for their
+# double buffers.
 CHAIN_VMEM_BUDGET_BYTES = 14 * 1024 * 1024
+
+# Scoped VMEM every conv/pool cell may use (v5e has 128 MiB per core;
+# Mosaic's default scope is 16 MiB).  The working-set budgets above stay
+# the planners' targets: the fp32-precision MXU contraction (three bf16
+# passes per operand) and the pipeline buffers come on top of the
+# modelled cell, so the limit leaves that room.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+# Lane width of a TPU vreg and of the MXU tile.  Mosaic accepts a block
+# whose minor dim is a multiple of this or spans the whole array dim, so
+# the paper's 4/8-output-channel blocks are raised to it.
+LANES = 128
 
 
 def _out_size(size, k, stride, pad):
@@ -418,7 +433,7 @@ def conv2d_basic_parallel(x, w, b, stride=(1, 1), padding=(0, 0), relu=False,
         ],
         out_specs=pl.BlockSpec((None, oc, oh, ow), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, oc, oh, ow), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,
@@ -498,52 +513,67 @@ def _plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
     return xp, phb, n_tiles, band, cband, ph, pw, row_step
 
 
-def _pool_epilogue(acc, o_ref, pool, conv_relu, lrn=None):
+def pool_stage_shapes(rows, ow, width):
+    """VMEM scratch that stages a ``[rows, ow, width]`` conv band for the
+    pool epilogue, one fp32 ref per lane chunk of at most ``LANES``
+    channels: Mosaic lowers a strided load only from a ref whose minor
+    dim fits one lane tile."""
+    return [pltpu.VMEM((rows, ow, min(LANES, width - lo)), jnp.float32)
+            for lo in range(0, width, LANES)]
+
+
+def _pool_staged(acc, p_refs, pool, conv_relu, phh, pww):
+    """Bias-added fp32 conv rows ``acc`` [conv_rows * conv_ow, W] →
+    (ReLU) → staged lane chunk by lane chunk in ``p_refs``
+    (``pool_stage_shapes``) → (ReLU) → fp32 pooled band [phh, pww, W]."""
+    from repro.kernels.pool2d.kernels import pool_band  # deferred: no cycle
+
+    pkh, pkw, psy, psx, kind, pool_relu, conv_ow = pool
+    if conv_relu:
+        acc = jnp.maximum(acc, 0.0)
+    cband = (phh - 1) * psy + pkh
+    band = acc.reshape(cband, conv_ow, -1)
+    parts = []
+    for ci, p_ref in enumerate(p_refs):
+        lo = ci * LANES
+        p_ref[...] = jax.lax.slice_in_dim(band, lo, lo + p_ref.shape[2],
+                                          axis=2)
+        parts.append(pool_band(p_ref, phh, pww, pkh, pkw, psy, psx, kind))
+    out = jnp.concatenate(parts, axis=-1)
+    if pool_relu:
+        out = jnp.maximum(out, 0.0)
+    return out
+
+
+def _pool_epilogue(acc, o_ref, p_refs, pool, conv_relu, lrn=None):
     """Shared epilogue: bias-added fp32 conv rows → (ReLU) → pooled band
     → (LRN).
 
     ``acc``: [conv_rows * conv_ow, OC] fp32; writes o_ref [PH_BLK, PW, OC].
-    ``lrn=(n, alpha, beta, k)`` normalizes the pooled band across channels
-    before the (single) HBM write — the conv AND pooled activations both
-    stay VMEM-resident.
+    The conv band is staged in the VMEM scratch ``p_refs`` so
+    ``pool_band`` can read its windows with strided ref loads.
+    ``lrn=(n, alpha, beta, k)`` normalizes the pooled band across
+    channels before the (single) HBM write — the conv AND pooled
+    activations both stay VMEM-resident.
     """
-    from repro.kernels.pool2d.kernels import pool_band  # deferred: no cycle
-
-    pkh, pkw, psy, psx, kind, pool_relu, conv_ow = pool
-    phh, pww, oc = o_ref.shape
-    if conv_relu:
-        acc = jnp.maximum(acc, 0.0)
-    cband = (phh - 1) * psy + pkh
-    out = pool_band(acc.reshape(cband, conv_ow, oc), phh, pww,
-                    pkh, pkw, psy, psx, kind)
-    if pool_relu:
-        out = jnp.maximum(out, 0.0)
+    phh, pww, _ = o_ref.shape
+    out = _pool_staged(acc, p_refs, pool, conv_relu, phh, pww)
     if lrn is not None:
         n, alpha, beta, k = lrn
         out = lrn_band(out, n, alpha, beta, k)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
-def _pool_epilogue_halo(acc, o_ref, pool, conv_relu, lrn):
+def _pool_epilogue_halo(acc, o_ref, p_refs, pool, conv_relu, lrn):
     """Channel-halo variant of ``_pool_epilogue`` for the oc-blocked LRN
     cell: ``acc`` holds ``ocb + n - 1`` conv channels (the tile's own
     plus the window's neighbour columns), the pooled band stays widened,
     and ``lrn_band_halo`` narrows it to the ``ocb`` core at the single
     HBM store.
     """
-    from repro.kernels.pool2d.kernels import pool_band  # deferred: no cycle
-
-    pkh, pkw, psy, psx, kind, pool_relu, conv_ow = pool
-    phh, pww, ocb = o_ref.shape
+    phh, pww, _ = o_ref.shape
     n, alpha, beta, k = lrn
-    ocw = ocb + n - 1
-    if conv_relu:
-        acc = jnp.maximum(acc, 0.0)
-    cband = (phh - 1) * psy + pkh
-    wide = pool_band(acc.reshape(cband, conv_ow, ocw), phh, pww,
-                     pkh, pkw, psy, psx, kind)
-    if pool_relu:
-        wide = jnp.maximum(wide, 0.0)
+    wide = _pool_staged(acc, p_refs, pool, conv_relu, phh, pww)
     o_ref[...] = lrn_band_halo(wide, n, alpha, beta, k).astype(o_ref.dtype)
 
 
@@ -552,34 +582,31 @@ def _pool_epilogue_halo(acc, o_ref, pool, conv_relu, lrn):
 # ---------------------------------------------------------------------------
 
 
-def _basic_simd_kernel(x_ref, w_ref, b_ref, o_ref, *, kh, kw, sy, sx, relu,
-                       pool=None, lrn=None):
-    # x_ref: [1, BAND, WP, C] (input-row band); w_ref: [KH, KW, C, OC];
-    # o_ref: [OH_BLK, OW, OC] (unfused) or [PH_BLK, PW, OC] (fused pool)
+def _basic_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
+                       sx, relu, pool=None, lrn=None):
+    # x_ref: [BAND, WP, C] (input-row band); w_ref: [KH, KW, C, OC];
+    # o_ref: [OH_BLK, OW, OC] (unfused) or [PH_BLK, PW, OC] (fused pool,
+    # p_refs staging the conv band: pool_stage_shapes)
     if pool is None:
         ohh, oww, oc = o_ref.shape
     else:
         pkh, _, psy, _, _, _, conv_ow = pool
         phh, _, oc = o_ref.shape
         ohh, oww = (phh - 1) * psy + pkh, conv_ow  # conv rows this cell owns
-    x = x_ref[0]
     acc = jnp.zeros((ohh * oww, oc), jnp.float32)
     for i in range(kh):
         for j in range(kw):
-            patch = jax.lax.slice(
-                x, (i, j, 0),
-                (i + (ohh - 1) * sy + 1, j + (oww - 1) * sx + 1,
-                 x.shape[2]),
-                (sy, sx, 1),
-            ).reshape(ohh * oww, -1)  # [rows, C] — C on the lane axis
+            patch = x_ref[pl.ds(i, ohh, stride=sy), pl.ds(j, oww, stride=sx),
+                          :].reshape(ohh * oww, -1)  # [rows, C]: C on lanes
             acc = acc + jnp.dot(
                 patch.astype(ACC_DTYPE),
                 w_ref[i, j].astype(ACC_DTYPE),
                 preferred_element_type=jnp.float32,
+                precision=ACC_PRECISION,
             )  # vectorized dot over channels (the paper's 4-wide, here 128)
     acc = acc + b_ref[...].astype(ACC_DTYPE)
     if pool is not None:  # fused super-layer: pool in VMEM, write pooled band
-        _pool_epilogue(acc, o_ref, pool, relu, lrn)
+        _pool_epilogue(acc, o_ref, p_refs, pool, relu, lrn)
         return
     if relu:
         acc = jnp.maximum(acc, 0.0)
@@ -604,17 +631,19 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         # activation stays in VMEM
         pkh, pkw = pool_kernel
         psy, psx = pool_stride if pool_stride is not None else pool_kernel
-        xp, phb, n_tiles, band, _, ph, pw, row_step = _plan_pool_tiles(
+        xp, phb, n_tiles, band, cband, ph, pw, row_step = _plan_pool_tiles(
             xp, oh, ow, kh, kw, sy, oh_block, oc,
             (pkh, pkw, psy, psx), im2col=False)
         pool = (pkh, pkw, psy, psx, pool_kind, pool_relu, ow)
         out_rows, out_cols = phb, pw
+        scratch = pool_stage_shapes(cband, ow, oc)
     else:
         xp, ohb, n_tiles, band = _plan_oh_tiles(xp, oh, kh, kw, sy, oh_block,
                                                 ow, oc, im2col=False)
         pool = None
         row_step = ohb * sy
         out_rows, out_cols = ohb, ow
+        scratch = []
     wp = xp.shape[2]
     kern = functools.partial(_basic_simd_kernel, kh=kh, kw=kw, sy=sy, sx=sx,
                              relu=relu, pool=pool, lrn=lrn)
@@ -623,21 +652,23 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         grid=(n, n_tiles),
         in_specs=[
             # element-offset indexing: bands overlap by the KH-sy halo rows
-            pl.BlockSpec((1, band, wp, c),
-                         lambda i, t: (i, t * row_step, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec((None,
+                          pl.Element(band), pl.Element(wp), pl.Element(c)),
+                         lambda i, t: (i, t * row_step, 0, 0)),
             pl.BlockSpec((kh, kw, c, oc), lambda i, t: (0, 0, 0, 0)),
-            pl.BlockSpec((oc,), lambda i, t: (0,)),
+            pl.BlockSpec((1, oc), lambda i, t: (0, 0)),
         ],
         out_specs=pl.BlockSpec((None, out_rows, out_cols, oc),
                                lambda i, t: (i, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * out_rows, out_cols, oc),
                                        x_nhwc.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel")
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-    )(xp, w_hwio, b)
+    )(xp, w_hwio, b.reshape(1, oc))
     return out[:, :ph] if pool_kernel is not None else out[:, :oh]
 
 
@@ -646,40 +677,46 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
 # ---------------------------------------------------------------------------
 
 
-def _advanced_simd_kernel(x_ref, w_ref, b_ref, o_ref, *, kh, kw, sy, sx,
-                          relu, pool=None, lrn=None):
-    # x_ref: [1, BAND, WP, C] (input-row band); w_ref: [KH*KW*C, OC_BLK];
-    # o_ref: [OH_BLK, OW, OC_BLK] (unfused) or [PH_BLK, PW, OC_BLK] (fused)
+def _im2col_band(x_ref, kh, kw, sy, sx, rows, ow):
+    """The [rows*ow, KH*KW*C] im2col patch matrix of an input-row band
+    ref, one strided ``pl.ds`` load per kernel position (Mosaic lowers
+    strided ref loads, not strided slices of a loaded value)."""
+    cols = []
+    for i in range(kh):  # im2col built once per spatial tile, reused for
+        for j in range(kw):  # the whole 128-wide output-channel block (§4.4)
+            cols.append(x_ref[pl.ds(i, rows, stride=sy),
+                              pl.ds(j, ow, stride=sx), :]
+                        .reshape(rows * ow, -1))
+    patches = jnp.concatenate(cols, axis=-1)  # [rows, KH*KW*C]
+    return patches
+
+
+def _advanced_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
+                          sx, relu, pool=None, lrn=None):
+    # x_ref: [BAND, WP, C] (input-row band); w_ref: [KH*KW*C, OC_BLK];
+    # o_ref: [OH_BLK, OW, OC_BLK] (unfused) or [PH_BLK, PW, OC_BLK] (fused,
+    # p_refs staging the conv band: pool_stage_shapes)
     if pool is None:
         ohh, oww, ocb = o_ref.shape
     else:
         pkh, _, psy, _, _, _, conv_ow = pool
         phh, _, ocb = o_ref.shape
         ohh, oww = (phh - 1) * psy + pkh, conv_ow  # conv rows this cell owns
-    x = x_ref[0]
-    cols = []
-    for i in range(kh):  # im2col built once per spatial tile, reused for
-        for j in range(kw):  # the whole 128-wide output-channel block (§4.4)
-            cols.append(jax.lax.slice(
-                x, (i, j, 0),
-                (i + (ohh - 1) * sy + 1, j + (oww - 1) * sx + 1,
-                 x.shape[2]),
-                (sy, sx, 1),
-            ).reshape(ohh * oww, -1))
-    patches = jnp.concatenate(cols, axis=-1)  # [rows, KH*KW*C]
+    patches = _im2col_band(x_ref, kh, kw, sy, sx, ohh, oww)
     acc = jnp.dot(patches.astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
-                  preferred_element_type=jnp.float32)  # one MXU matmul
+                  preferred_element_type=jnp.float32,
+                  precision=ACC_PRECISION)  # one MXU matmul
     acc = acc + b_ref[...].astype(ACC_DTYPE)
     if pool is not None:  # fused super-layer: pool in VMEM, write pooled band
-        _pool_epilogue(acc, o_ref, pool, relu, lrn)
+        _pool_epilogue(acc, o_ref, p_refs, pool, relu, lrn)
         return
     if relu:  # fused epilogue in VMEM — zero-cost ReLU (Fig. 5)
         acc = jnp.maximum(acc, 0.0)
     o_ref[...] = acc.reshape(ohh, oww, ocb).astype(o_ref.dtype)
 
 
-def _advanced_simd_halo_kernel(x_ref, w_ref, b_ref, o_ref, *, kh, kw, sy, sx,
-                               relu, pool, lrn):
+def _advanced_simd_halo_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw,
+                               sy, sx, relu, pool, lrn):
     # two-pass channel-halo cell: w_ref/b_ref are widened to the tile's
     # ocb + n - 1 columns (its own channels plus the LRN window's
     # neighbours), so conv+pool run over the widened tile and
@@ -688,56 +725,59 @@ def _advanced_simd_halo_kernel(x_ref, w_ref, b_ref, o_ref, *, kh, kw, sy, sx,
     pkh, _, psy, _, _, _, conv_ow = pool
     phh = o_ref.shape[0]
     ohh, oww = (phh - 1) * psy + pkh, conv_ow  # conv rows this cell owns
-    xin = x_ref[0]
-    parts = []
-    for i in range(kh):
-        for j in range(kw):
-            parts.append(jax.lax.slice(
-                xin, (i, j, 0),
-                (i + (ohh - 1) * sy + 1, j + (oww - 1) * sx + 1,
-                 xin.shape[2]),
-                (sy, sx, 1),
-            ).reshape(ohh * oww, -1))
-    pmat = jnp.concatenate(parts, axis=-1)  # [rows, KH*KW*C]
+    pmat = _im2col_band(x_ref, kh, kw, sy, sx, ohh, oww)
     acc = jnp.dot(pmat.astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32,
+                  precision=ACC_PRECISION)
     acc = acc + b_ref[...].astype(ACC_DTYPE)
-    _pool_epilogue_halo(acc, o_ref, pool, relu, lrn)
+    _pool_epilogue_halo(acc, o_ref, p_refs, pool, relu, lrn)
 
 
-def _advanced_simd_carry_kernel(x_ref, w_ref, b_ref, o_ref, c_ref, *, kh, kw,
-                                sy, sx, relu, pool, k_rows):
+def _advanced_simd_carry_kernel(x_ref, w_ref, b_ref, o_ref, c_ref, *p_refs,
+                                kh, kw, sy, sx, relu, pool, k_rows):
     # sliding-window pool accumulator: each band step convolves only its
     # R = PH_BLK*psy fresh conv rows; the K = pkh - psy boundary rows are
     # carried in VMEM scratch (c_ref) from the previous step instead of
     # being re-read and re-convolved from the input band.  Step 0 is a
     # pure seed band over the zero prepad (its output block is sliced off
-    # host-side); its last K fresh rows are conv rows [0, K).
+    # host-side); its last K fresh rows are conv rows [0, K).  p_refs
+    # stage the carried + fresh conv band for the pool epilogue.
     pkh, _, psy, _, _, _, conv_ow = pool
     phh, _, ocb = o_ref.shape
     r_rows = phh * psy  # fresh conv rows this step owns
-    xin = x_ref[0]
-    parts = []
-    for i in range(kh):
-        for j in range(kw):
-            parts.append(jax.lax.slice(
-                xin, (i, j, 0),
-                (i + (r_rows - 1) * sy + 1, j + (conv_ow - 1) * sx + 1,
-                 xin.shape[2]),
-                (sy, sx, 1),
-            ).reshape(r_rows * conv_ow, -1))
-    pmat = jnp.concatenate(parts, axis=-1)  # [rows, KH*KW*C]
+    pmat = _im2col_band(x_ref, kh, kw, sy, sx, r_rows, conv_ow)
     acc = jnp.dot(pmat.astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32,
+                  precision=ACC_PRECISION)
     acc = acc + b_ref[...].astype(ACC_DTYPE)
     fresh = acc.reshape(r_rows, conv_ow, ocb)
     whole = jnp.concatenate([c_ref[...], fresh], axis=0)
     _pool_epilogue(whole.reshape((k_rows + r_rows) * conv_ow, ocb), o_ref,
-                   pool, relu)
+                   p_refs, pool, relu)
     # slide the window: the LAST K fresh conv rows become the next band's
     # carried head (pre-ReLU fp32 — the epilogue re-applies ReLU on read,
     # so every pooled output still sees relu(conv) exactly once)
     c_ref[...] = jax.lax.slice_in_dim(fresh, r_rows - k_rows, r_rows, axis=0)
+
+
+def _pack_width_taps(xp, wmat, kh, kw, sx, ow):
+    """Unfold a conv's kernel-width taps into the lane axis: column ``q``
+    of the result holds input columns ``q*sx + j`` (``j < kw``) side by
+    side, zero-padded to ``LANES`` channels, and the im2col weight rows
+    are reordered to match.  The in-kernel patch build then joins ``kh``
+    lane-aligned pieces instead of ``kh*kw`` narrow ones, which Mosaic
+    would lower to one lane shuffle per piece and sublane tile (AlexNet
+    conv1: 121 pieces of 8 lanes)."""
+    n, hp, _, c = xp.shape
+    taps = [jax.lax.slice(xp, (0, 0, j, 0),
+                          (n, hp, j + (ow - 1) * sx + 1, c), (1, 1, sx, 1))
+            for j in range(kw)]
+    xu = jnp.pad(jnp.concatenate(taps, axis=-1),
+                 ((0, 0), (0, 0), (0, 0), (0, LANES - kw * c)))
+    oc = wmat.shape[1]
+    wu = jnp.pad(wmat.reshape(kh, kw * c, oc),
+                 ((0, 0), (0, LANES - kw * c), (0, 0)))
+    return xu, wu.reshape(kh * LANES, oc)
 
 
 def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
@@ -783,16 +823,19 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         halo_hi = lrn[0] - 1 - halo_lo
         wmat = jnp.pad(wmat, ((0, 0), (halo_lo, halo_hi)))
         b = jnp.pad(b, (halo_lo, halo_hi))
+    b = b.reshape(1, -1)  # 2-D: Mosaic tiles a 1-D block unlike XLA
     if pool_kernel is not None:
         # fused super-layer: each cell writes a pooled band, the conv
         # activation stays in VMEM
-        xp, phb, n_tiles, band, _, ph, pw, row_step = _plan_pool_tiles(
+        xp, phb, n_tiles, band, cband, ph, pw, row_step = _plan_pool_tiles(
             xp, oh, ow, kh, kw, sy, oh_block, ocb, (pkh, pkw, psy, psx),
             oc_halo=oc_halo)
         pool = (pkh, pkw, psy, psx, pool_kind, pool_relu, ow)
         out_rows, out_cols = phb, pw
         carry = resolve_pool_carry(pool_carry, True, lrn,
                                    (pkh, pkw, psy, psx), phb, n_tiles)
+        # the conv band the pool epilogue stages in VMEM
+        scratch = pool_stage_shapes(cband, ow, ocb + oc_halo)
     else:
         xp, ohb, n_tiles, band = _plan_oh_tiles(xp, oh, kh, kw, sy, oh_block,
                                                 ow, ocb)
@@ -800,6 +843,7 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         row_step = ohb * sy
         out_rows, out_cols = ohb, ow
         carry = False
+        scratch = []
     wp = xp.shape[2]
     if carry:
         k_rows = pkh - psy        # conv rows carried between band steps
@@ -813,6 +857,13 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         # rows _plan_pool_tiles already padded are exactly what the
         # shifted bands need (the prepad algebra cancels to zero extra).
         xp = jnp.pad(xp, ((0, 0), (prepad, 0), (0, 0), (0, 0)))
+    if kw > 1 and kw * c <= LANES:
+        # a kernel row's taps fit one lane tile: unfold them host-side,
+        # the cell then runs a (kh, 1) stride-(sy, 1) conv.  Row bands
+        # are unchanged, so the geometry resolved above still holds.
+        xp, wmat = _pack_width_taps(xp, wmat, kh, kw, sx, ow)
+        wp, c, kw, sx = ow, LANES, 1, 1
+    if carry:
         oc_tiles = ocp // ocb
         kern = functools.partial(_advanced_simd_carry_kernel, kh=kh, kw=kw,
                                  sy=sy, sx=sx, relu=relu, pool=pool,
@@ -823,50 +874,61 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
             in_specs=[
                 # element-offset indexing; the carried rows replace the
                 # pool-window share of the inter-band halo
-                pl.BlockSpec((1, band, wp, c),
-                             lambda i, u, j: (i, j * row_step, 0, 0),
-                             indexing_mode=pl.Unblocked()),
+                pl.BlockSpec((None,
+                              pl.Element(band), pl.Element(wp), pl.Element(c)),
+                             lambda i, u, j: (i, j * row_step, 0, 0)),
                 pl.BlockSpec((kh * kw * c, ocb), lambda i, u, j: (0, u)),
-                pl.BlockSpec((ocb,), lambda i, u, j: (u,)),
+                pl.BlockSpec((1, ocb), lambda i, u, j: (0, u)),
             ],
             out_specs=pl.BlockSpec((None, out_rows, out_cols, ocb),
                                    lambda i, u, j: (i, j, 0, u)),
             out_shape=jax.ShapeDtypeStruct(
                 (n, (n_tiles + 1) * out_rows, out_cols, ocp), x_nhwc.dtype),
-            scratch_shapes=[pltpu.VMEM((k_rows, ow, ocb), jnp.float32)],
-            compiler_params=pltpu.TPUCompilerParams(
+            scratch_shapes=[pltpu.VMEM((k_rows, ow, ocb), jnp.float32)]
+            + scratch,
+            compiler_params=pltpu.CompilerParams(
                 # the band axis is sequential: each step consumes the
                 # carry its predecessor left in scratch
-                dimension_semantics=("parallel", "parallel", "arbitrary")
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
         )(xp, wmat, b)
         return out[:, out_rows:out_rows + ph, :, :oc]
     if oc_halo:
         oc_tiles = ocp // ocb
+        ocw = ocb + oc_halo
+        # adjacent weight tiles overlap by the n-1 halo columns: lay each
+        # widened tile out on its own leading index (Mosaic blocks the
+        # lane axis only at multiples of 128 or the whole dim)
+        wmat = jnp.concatenate(
+            [jax.lax.slice_in_dim(wmat, u * ocb, u * ocb + ocw, axis=1)
+             .reshape(1, kh * kw * c, ocw) for u in range(oc_tiles)], axis=0)
+        b = jnp.concatenate(
+            [jax.lax.slice_in_dim(b, u * ocb, u * ocb + ocw, axis=1)
+             .reshape(1, 1, ocw) for u in range(oc_tiles)], axis=0)
         kern = functools.partial(_advanced_simd_halo_kernel, kh=kh, kw=kw,
                                  sy=sy, sx=sx, relu=relu, pool=pool, lrn=lrn)
         out = pl.pallas_call(
             kern,
             grid=(n, n_tiles, oc_tiles),
             in_specs=[
-                # element-offset indexing on rows AND weight columns:
-                # adjacent weight tiles overlap by the n-1 halo columns
-                pl.BlockSpec((1, band, wp, c),
-                             lambda i, t, u: (i, t * row_step, 0, 0),
-                             indexing_mode=pl.Unblocked()),
-                pl.BlockSpec((kh * kw * c, ocb + oc_halo),
-                             lambda i, t, u: (0, u * ocb),
-                             indexing_mode=pl.Unblocked()),
-                pl.BlockSpec((ocb + oc_halo,), lambda i, t, u: (u * ocb,),
-                             indexing_mode=pl.Unblocked()),
+                # element-offset indexing: bands overlap by the halo rows
+                pl.BlockSpec((None,
+                              pl.Element(band), pl.Element(wp), pl.Element(c)),
+                             lambda i, t, u: (i, t * row_step, 0, 0)),
+                pl.BlockSpec((None, kh * kw * c, ocw),
+                             lambda i, t, u: (u, 0, 0)),
+                pl.BlockSpec((None, 1, ocw), lambda i, t, u: (u, 0, 0)),
             ],
             out_specs=pl.BlockSpec((None, out_rows, out_cols, ocb),
                                    lambda i, t, u: (i, t, 0, u)),
             out_shape=jax.ShapeDtypeStruct(
                 (n, n_tiles * out_rows, out_cols, ocp), x_nhwc.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
         )(xp, wmat, b)
@@ -878,18 +940,20 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         grid=(n, n_tiles, ocp // ocb),
         in_specs=[
             # element-offset indexing: bands overlap by the KH-sy halo rows
-            pl.BlockSpec((1, band, wp, c),
-                         lambda i, t, o: (i, t * row_step, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec((None,
+                          pl.Element(band), pl.Element(wp), pl.Element(c)),
+                         lambda i, t, o: (i, t * row_step, 0, 0)),
             pl.BlockSpec((kh * kw * c, ocb), lambda i, t, o: (0, o)),
-            pl.BlockSpec((ocb,), lambda i, t, o: (o,)),
+            pl.BlockSpec((1, ocb), lambda i, t, o: (0, o)),
         ],
         out_specs=pl.BlockSpec((None, out_rows, out_cols, ocb),
                                lambda i, t, o: (i, t, 0, o)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * out_rows, out_cols, ocp),
                                        x_nhwc.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(xp, wmat, b)
@@ -998,9 +1062,14 @@ def chain_cell_bytes(blk, h, w, c, chain, ocs, pool,
     in_rows, in_w = band, w + 2 * chain[0][5]
     for i, ((kh, kw, sy, sx, py, px), (oh, ow, ci, oc)) in enumerate(
             zip(chain, dims)):
+        # Mosaic single-buffers a grid-invariant whole-array block and
+        # double-buffers one that moves: the blocked final stage's
+        # weights are charged twice
+        copies = 1
         if i == last and oc_block_final is not None:
+            copies = 2 if oc_block_final < oc else 1
             oc = min(oc_block_final, oc)
-        weights += kh * kw * ci * oc
+        weights += copies * kh * kw * ci * oc
         patch_c = kh * kw * ci if im2col else ci
         stage_peak = max(stage_peak,
                          in_rows * in_w * ci     # incoming band
@@ -1085,7 +1154,8 @@ def _band_conv(x, w_ref, kh, kw, sy, sx, m, ow, im2col):
                 ).reshape(m * ow, -1))
         patches = jnp.concatenate(cols, axis=-1)  # [rows, KH*KW*C]
         return jnp.dot(patches, w_ref[...].astype(ACC_DTYPE),
-                       preferred_element_type=jnp.float32)
+                       preferred_element_type=jnp.float32,
+                       precision=ACC_PRECISION)
     acc = jnp.zeros((m * ow, w_ref.shape[-1]), jnp.float32)
     for i in range(kh):
         for j in range(kw):
@@ -1096,21 +1166,24 @@ def _band_conv(x, w_ref, kh, kw, sy, sx, m, ow, im2col):
             ).reshape(m * ow, -1)
             # vectorized dot over channels per kernel position (§4.3)
             acc = acc + jnp.dot(patch, w_ref[i, j].astype(ACC_DTYPE),
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32,
+                                precision=ACC_PRECISION)
     return acc
 
 
 def _chain_simd_kernel(x_ref, *refs, stages, pool, lrn, im2col):
-    # x_ref: [1, BAND, WP0, C] (halo-widened chain-input band);
-    # refs: (w0, b0, w1, b1, ..., o_ref); stages: per-stage static tuples
+    # x_ref: [BAND, WP0, C] (halo-widened chain-input band);
+    # refs: (w0, b0, w1, b1, ..., o_ref, *p_refs) — p_refs, the pool
+    # tail's conv-band staging scratch, only when pool is set; stages:
+    # per-stage static tuples
     # (kh, kw, sy, sx, px, m, ow, relu, oh_valid, A, B) where px is the
     # stage's own horizontal padding (0 for stage 0 — host-applied),
     # m/ow the stage's band geometry, oh_valid its true output height and
     # (A, B) the affine band-index→global-row map for the padding mask.
-    o_ref = refs[-1]
-    wb = refs[:-1]
+    n_wb = 2 * len(stages)
+    wb, o_ref = refs[:n_wb], refs[n_wb]
     t = pl.program_id(1)
-    band = x_ref[0].astype(ACC_DTYPE)
+    band = x_ref[...].astype(ACC_DTYPE)
     last = len(stages) - 1
     for si, (kh, kw, sy, sx, px, m, ow, relu, oh_valid, a, b0) in enumerate(
             stages):
@@ -1121,7 +1194,8 @@ def _chain_simd_kernel(x_ref, *refs, stages, pool, lrn, im2col):
         acc = acc + wb[2 * si + 1][...].astype(ACC_DTYPE)
         if si == last:
             if pool is not None:  # pool(/LRN) the final band in VMEM
-                _pool_epilogue(acc, o_ref, pool, relu, lrn)
+                _pool_epilogue(acc, o_ref, refs[n_wb + 1:], pool, relu,
+                               lrn)
             else:
                 if relu:
                     acc = jnp.maximum(acc, 0.0)
@@ -1196,6 +1270,10 @@ def conv2d_chain_simd(x_nhwc, ws, bs, strides, paddings, relus,
                                        oh_block, im2col=im2col,
                                        oc_block_final=oc_block_final)
     m, offs, band, in_step, in_base = chain_band_geometry(blk, chain, pool_g)
+    # the pool tail stages the final conv band in VMEM
+    ocw_f = oc_f if oc_block_final is None else oc_block_final
+    scratch = ([] if pool is None else
+               pool_stage_shapes(m[-1], ow_f, ocw_f))
     # stage-0 padding host-side (+ the extra top rows the intermediate
     # vertical padding pulls the first band up into, all genuine zeros)
     py0, px0 = paddings[0]
@@ -1225,9 +1303,9 @@ def conv2d_chain_simd(x_nhwc, ws, bs, strides, paddings, relus,
             blast = jnp.pad(blast, (0, pad_f))
         ocp_f = oc_f + pad_f
         in_specs = [
-            pl.BlockSpec((1, band, wp0, c),
-                         lambda i, t, o: (i, t * in_step + base, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec((None,
+                          pl.Element(band), pl.Element(wp0), pl.Element(c)),
+                         lambda i, t, o: (i, t * in_step + base, 0, 0)),
         ]
         operands = [xp]
         last_w = s - 1
@@ -1251,12 +1329,13 @@ def conv2d_chain_simd(x_nhwc, ws, bs, strides, paddings, relus,
                 else:
                     in_specs.append(pl.BlockSpec(
                         (kh, kw, ci, oc), lambda i, t, o: (0, 0, 0, 0)))
-            operands.append(b)
+            operands.append(b.reshape(1, oc))
             if si == last_w:
-                in_specs.append(pl.BlockSpec((ocb_f,),
-                                             lambda i, t, o: (o,)))
+                in_specs.append(pl.BlockSpec((1, ocb_f),
+                                             lambda i, t, o: (0, o)))
             else:
-                in_specs.append(pl.BlockSpec((oc,), lambda i, t, o: (0,)))
+                in_specs.append(pl.BlockSpec((1, oc),
+                                             lambda i, t, o: (0, 0)))
         out = pl.pallas_call(
             kern,
             grid=(n, n_tiles, ocp_f // ocb_f),
@@ -1265,17 +1344,18 @@ def conv2d_chain_simd(x_nhwc, ws, bs, strides, paddings, relus,
                                    lambda i, t, o: (i, t, 0, o)),
             out_shape=jax.ShapeDtypeStruct(
                 (n, n_tiles * blk, out_cols, ocp_f), x_nhwc.dtype),
-            compiler_params=pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")
+            scratch_shapes=scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES,
             ),
             interpret=interpret,
         )(*operands)
         return out[:, :target, :, :oc_f]
     in_specs = [
         # element-offset indexing: chain bands overlap by the composed halo
-        pl.BlockSpec((1, band, wp0, c),
-                     lambda i, t: (i, t * in_step + base, 0, 0),
-                     indexing_mode=pl.Unblocked()),
+        pl.BlockSpec((None, pl.Element(band), pl.Element(wp0), pl.Element(c)),
+                     lambda i, t: (i, t * in_step + base, 0, 0)),
     ]
     operands = [xp]
     for w, b in zip(ws, bs):
@@ -1288,8 +1368,8 @@ def conv2d_chain_simd(x_nhwc, ws, bs, strides, paddings, relus,
             operands.append(w)
             in_specs.append(pl.BlockSpec((kh, kw, ci, oc),
                                          lambda i, t: (0, 0, 0, 0)))
-        operands.append(b)
-        in_specs.append(pl.BlockSpec((oc,), lambda i, t: (0,)))
+        operands.append(b.reshape(1, oc))
+        in_specs.append(pl.BlockSpec((1, oc), lambda i, t: (0, 0)))
     out = pl.pallas_call(
         kern,
         grid=(n, n_tiles),
@@ -1298,8 +1378,10 @@ def conv2d_chain_simd(x_nhwc, ws, bs, strides, paddings, relus,
                                lambda i, t: (i, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * blk, out_cols, oc_f),
                                        x_nhwc.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel")
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(*operands)

@@ -24,14 +24,11 @@ from repro.core.layout import (
     oihw_to_hwio,
     pad_axis,
 )
+from repro.kernels.common import interpret_default
 from repro.kernels.conv2d import kernels as K
 from repro.kernels.conv2d.ref import conv2d_ref
 
 SUBLANES = 8  # channel padding multiple (paper's "divisible by 4", on TPU 8/128)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("stride", "padding", "relu", "method",
@@ -64,7 +61,7 @@ def conv2d(x, w, b, stride=(1, 1), padding=(0, 0), relu=False,
     two-pass channel-halo LRN cell (oc blocking with window-widened
     weight tiles).  ``None`` = the kernel resolvers decide.
     """
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_default() if interpret is None else interpret
     if method == "basic_parallel":
         if pool_kernel is not None or lrn_n is not None:
             raise ValueError("fused pooling epilogue requires a SIMD method")
@@ -86,9 +83,9 @@ def conv2d(x, w, b, stride=(1, 1), padding=(0, 0), relu=False,
                                   pool_kind=pool_kind, pool_relu=pool_relu,
                                   lrn=lrn)
     elif method.startswith("advanced_simd"):
-        blk = int(method.rsplit("_", 1)[1]) if method[-1].isdigit() else 128
+        # the paper's 4/8 outputs per thread, raised to the MXU lane tile
         out = K.conv2d_advanced_simd(xh, wh, b, stride, padding, relu,
-                                     oc_block=blk, oh_block=oh_block,
+                                     oc_block=K.LANES, oh_block=oh_block,
                                      interpret=interp,
                                      pool_kernel=pool_kernel,
                                      pool_stride=pool_stride,
@@ -132,7 +129,7 @@ def conv2d_chain(x, ws, bs, strides, paddings, relus,
     if lrn_n is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
     lrn = (lrn_n, lrn_alpha, lrn_beta, lrn_k) if lrn_n is not None else None
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_default() if interpret is None else interpret
     im2col = method.startswith("advanced_simd")
     xh = nchw_to_nhwc(x)
     xh, _ = pad_axis(xh, 3, SUBLANES)

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import ACC_DTYPE
+from repro.kernels.common import ACC_DTYPE, ACC_PRECISION
 
 
 def _act(y, act: str):
@@ -44,6 +44,7 @@ def _kernel(x_ref, w_ref, b_ref, o_ref, *, act: str, nk: int):
     o_ref[...] += jnp.dot(
         x_ref[...].astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
         preferred_element_type=jnp.float32,
+        precision=ACC_PRECISION,
     )
 
     @pl.when(k == nk - 1)
@@ -81,8 +82,9 @@ def matmul_fused_pallas(
     ]
     args = [x, w]
     if b is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, k: (j,)))
-        args.append(b)
+        # 2-D bias: Mosaic tiles a 1-D block unlike XLA's 1-D layout
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
+        args.append(b.reshape(1, Np))
     else:
         kernel = functools.partial(_kernel, act=act, nk=nk)
 
@@ -97,7 +99,7 @@ def matmul_fused_pallas(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -11,18 +11,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import interpret_default
 from repro.kernels.matmul_fused.kernel import matmul_fused_pallas
 from repro.kernels.matmul_fused.ref import matmul_fused_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("act", "interpret"))
 def matmul_fused(x, w, b=None, act: str = "none", interpret: bool = None):
     """y = act(x @ w + b).  Leading dims of x are flattened to M."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_default() if interpret is None else interpret
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     y = matmul_fused_pallas(x2, w, b, act=act, interpret=interp)

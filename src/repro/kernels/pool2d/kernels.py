@@ -3,15 +3,16 @@
 Same grid-over-frames structure as the conv ladder: grid cell
 ``(frame, oh-tile)``; each cell loads only the input-row band its output
 band needs — ``(oh_block-1)*stride + KH`` rows including the halo — via a
-stride-aware element-offset (``pl.Unblocked``) BlockSpec, exactly the
+stride-aware element-offset (``pl.Element``) block dim, exactly the
 PR 1 conv plumbing.  This replaces the engine's bare ``reduce_window``
 ("accelerated on mobile CPU" in the paper) with a VMEM-resident kernel so
 pooling joins the ladder and can be fused as a conv epilogue.
 
 ``pool_band`` is the shared in-VMEM pooling primitive: it reduces an
-fp32 ``[H, W, C]`` band to ``[ph, pw, C]`` with unrolled window loops.
-The fused conv kernels in ``repro.kernels.conv2d.kernels`` call it on
-their conv accumulator so the intermediate activation never leaves VMEM.
+``[H, W, C]`` band ref to fp32 ``[ph, pw, C]`` with unrolled window
+loops.  The fused conv kernels in ``repro.kernels.conv2d.kernels`` stage
+their conv accumulator in a VMEM scratch ref and pool it there, so the
+intermediate activation never leaves VMEM.
 """
 from __future__ import annotations
 
@@ -25,7 +26,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import ACC_DTYPE
 
 from repro.kernels.conv2d.kernels import (
+    LANES,
     VMEM_BUDGET_BYTES,
+    VMEM_LIMIT_BYTES,
     _band_rows,
     auto_oh_block,
     band_intervals,
@@ -36,14 +39,15 @@ def _out_size(size, k, stride):
     return (size - k) // stride + 1
 
 
-def pool_band(x, ph, pw, pkh, pkw, psy, psx, kind: str):
-    """Pool an fp32 ``[H, W, C]`` band down to ``[ph, pw, C]``.
+def pool_band(x_ref, ph, pw, pkh, pkw, psy, psx, kind: str):
+    """Pool an ``[H, W, C]`` band ref down to fp32 ``[ph, pw, C]``.
 
-    Unrolled over the (small, static) pool window; strided
-    ``jax.lax.slice`` picks each window position's contribution, so the
+    Unrolled over the (small, static) pool window; a strided ``pl.ds``
+    load picks each window position's contribution (Mosaic lowers
+    strided ref loads, not strided slices of a loaded value), so the
     reduction is pure VPU work on data already in VMEM.
     """
-    c = x.shape[2]
+    c = x_ref.shape[2]
     if kind == "max":
         acc = jnp.full((ph, pw, c), -jnp.inf, jnp.float32)
     elif kind == "avg":
@@ -52,11 +56,8 @@ def pool_band(x, ph, pw, pkh, pkw, psy, psx, kind: str):
         raise ValueError(kind)
     for i in range(pkh):
         for j in range(pkw):
-            win = jax.lax.slice(
-                x, (i, j, 0),
-                (i + (ph - 1) * psy + 1, j + (pw - 1) * psx + 1, c),
-                (psy, psx, 1),
-            )  # [ph, pw, C]
+            win = x_ref[pl.ds(i, ph, stride=psy), pl.ds(j, pw, stride=psx),
+                        :].astype(ACC_DTYPE)  # [ph, pw, C]
             if kind == "max":
                 acc = jnp.maximum(acc, win)
             else:
@@ -82,10 +83,9 @@ def auto_oh_block_pool(oh, ow, wp, c, kh, sy,
 
 
 def _pool2d_kernel(x_ref, o_ref, *, kh, kw, sy, sx, kind, relu):
-    # x_ref: [1, BAND, WP, C] (input-row band); o_ref: [OH_BLK, OW, C]
+    # x_ref: [BAND, WP, C] (input-row band); o_ref: [OH_BLK, OW, C]
     ohh, oww, _ = o_ref.shape
-    acc = pool_band(x_ref[0].astype(ACC_DTYPE), ohh, oww, kh, kw, sy, sx,
-                    kind)
+    acc = pool_band(x_ref, ohh, oww, kh, kw, sy, sx, kind)
     if relu:
         acc = jnp.maximum(acc, 0.0)
     o_ref[...] = acc.astype(o_ref.dtype)
@@ -111,26 +111,32 @@ def pool2d_nhwc(x_nhwc, kernel=(2, 2), stride=(2, 2), kind: str = "max",
     # the surplus pooled rows only read pad and are sliced off below
     _, in_iv = band_intervals(n_tiles, ohb, oh, row_step, band)
     hp_need = in_iv[-1][0] + band
-    if hp_need > h:
-        x_nhwc = jnp.pad(x_nhwc, ((0, 0), (0, hp_need - h), (0, 0), (0, 0)))
+    # channel blocks of at most one lane tile: Mosaic lowers the strided
+    # window loads only from a block whose minor dim fits one
+    cb = min(c, LANES)
+    pad_c = (-c) % cb
+    if hp_need > h or pad_c:
+        x_nhwc = jnp.pad(x_nhwc, ((0, 0), (0, max(0, hp_need - h)), (0, 0),
+                                  (0, pad_c)))
     kern = functools.partial(_pool2d_kernel, kh=kh, kw=kw, sy=sy, sx=sx,
                              kind=kind, relu=relu)
     out = pl.pallas_call(
         kern,
-        grid=(n, n_tiles),
+        grid=(n, n_tiles, (c + pad_c) // cb),
         in_specs=[
             # element-offset indexing: bands overlap by the KH-sy halo rows
-            pl.BlockSpec((1, band, wd, c),
-                         lambda i, t: (i, t * row_step, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            pl.BlockSpec((None,
+                          pl.Element(band), pl.Element(wd), pl.Element(cb)),
+                         lambda i, t, k: (i, t * row_step, 0, k * LANES)),
         ],
-        out_specs=pl.BlockSpec((None, ohb, ow, c),
-                               lambda i, t: (i, t, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, n_tiles * ohb, ow, c),
+        out_specs=pl.BlockSpec((None, ohb, ow, cb),
+                               lambda i, t, k: (i, t, 0, k)),
+        out_shape=jax.ShapeDtypeStruct((n, n_tiles * ohb, ow, c + pad_c),
                                        x_nhwc.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(x_nhwc)
-    return out[:, :oh]
+    return out[:, :oh, :, :c]

@@ -12,13 +12,10 @@ from functools import partial
 import jax
 
 from repro.core.layout import nchw_to_nhwc, nhwc_to_nchw, pad_axis
+from repro.kernels.common import interpret_default
 from repro.kernels.pool2d import kernels as K
 
 SUBLANES = 8  # channel padding multiple (mirrors conv2d.ops)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("kernel", "stride", "kind", "relu",
@@ -27,7 +24,7 @@ def pool2d(x, kernel=(2, 2), stride=(2, 2), kind: str = "max",
            relu: bool = False, oh_block: int = None,
            interpret: bool = None):
     """x: [N, C, H, W]; VALID window semantics."""
-    interp = (not _on_tpu()) if interpret is None else interpret
+    interp = interpret_default() if interpret is None else interpret
     xh = nchw_to_nhwc(x)
     xh, orig_c = pad_axis(xh, 3, SUBLANES)  # pad value 0 never crosses
     out = K.pool2d_nhwc(xh, kernel, stride, kind, relu,  # channel lanes

@@ -98,7 +98,7 @@ def wkv6_pallas(r, k, v, logw, u, *, chunk: int = 32,
         out_specs=pl.BlockSpec((None, L, e), lambda g, c: (g, c, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sp, e), r.dtype),
         scratch_shapes=[pltpu.VMEM((e, e), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf)

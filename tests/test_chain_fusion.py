@@ -144,22 +144,23 @@ def test_planner_blocks_final_stage_before_dropping_conv():
     """A budget too small for the full chain but large enough for its
     oc-blocked-final-stage variant keeps the WHOLE chain, with
     ``oc_block_final`` recorded on the group — the new admission rung
-    fires before any trailing conv is popped."""
+    fires before any trailing conv is popped.  The rung blocks at the
+    128-lane MXU tile, so the final stage is wider than that."""
     net = NetworkDef("t", (64, 16, 64), 4, (
-        _conv("c1", 64), _conv("c2", 64), _conv("c3", 64),
+        _conv("c1", 64), _conv("c2", 64), _conv("c3", 512),
         LayerSpec("pool", "p", kernel=(2, 2), stride=(2, 2)),
     ))
     convs = [l for l in net.layers if l.kind == "conv"]
     pool = net.layers[-1]
     need3 = chain_working_set(convs, pool, SIMD, 64, 16, 64)
     need3_blocked = chain_working_set(convs, pool, SIMD, 64, 16, 64,
-                                      oc_block_final=8)
+                                      oc_block_final=128)
     assert need3_blocked < need3
     plan = plan_fusion(net, method_for=lambda n: SIMD,
                        vmem_budget=(need3_blocked + need3) // 2)
     assert fusion_summary(plan) == [("c1", "c2", "c3", "p")]
     (g,) = [it for it in plan if isinstance(it, FusedLayerSpec)]
-    assert g.oc_block_final == 8
+    assert g.oc_block_final == 128
 
 
 def test_chain_cell_bytes_shrinks_with_oc_block_final():

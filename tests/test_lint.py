@@ -32,7 +32,7 @@ def test_r001_threaded_kwargs_clean():
     src = """
 out = pl.pallas_call(
     kern, grid=(n,), out_shape=shape,
-    compiler_params=pltpu.TPUCompilerParams(
+    compiler_params=pltpu.CompilerParams(
         dimension_semantics=("parallel",)),
     interpret=interpret,
 )(x)
@@ -105,13 +105,12 @@ class Engine:
     assert lint_source(src) == []
 
 
-# -- R003: Unblocked index maps --------------------------------------------
+# -- R003: element-offset index maps ---------------------------------------
 
 def test_r003_inline_arithmetic():
     src = """
-spec = pl.BlockSpec((1, band, wp, c),
-                    lambda i, t: (i, t * 8, 0, 0),
-                    indexing_mode=pl.Unblocked())
+spec = pl.BlockSpec((1, pl.Element(band), wp, c),
+                    lambda i, t: (i, t * 8, 0, 0))
 """
     findings = lint_source(src)
     assert rules_of(findings) == {"R003"}
@@ -119,15 +118,15 @@ spec = pl.BlockSpec((1, band, wp, c),
 
 def test_r003_resolver_named_offset_clean():
     src = """
-spec = pl.BlockSpec((1, band, wp, c),
-                    lambda i, t: (i, t * row_step, 0, 0),
-                    indexing_mode=pl.Unblocked())
+spec = pl.BlockSpec((1, pl.Element(band), wp, c),
+                    lambda i, t: (i, t * row_step, 0, 0))
 """
     assert lint_source(src) == []
 
 
 def test_r003_blocked_spec_literals_allowed():
-    # block-index (non-Unblocked) specs index in block units; literals fine
+    # block-index (no pl.Element dim) specs index in block units; literals
+    # fine
     src = """
 spec = pl.BlockSpec((None, 4, oh, ow), lambda i, t: (i, t * 2, 0, 0))
 """

@@ -22,6 +22,25 @@ SIMD = Method.ADVANCED_SIMD_8
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("use_pallas,raises", [(True, True), (False, False)])
+def test_compile_plan_refuses_kernel_without_tpu_lowering(monkeypatch,
+                                                          use_pallas,
+                                                          raises):
+    """On a TPU backend a Pallas plan that selects a method Mosaic cannot
+    compile (``basic_parallel``) raises at compile time instead of
+    falling back to another path; the XLA path still plans."""
+    monkeypatch.setattr(plan_mod.jax, "default_backend", lambda: "tpu")
+    net = NETWORKS["lenet5"]()
+    if raises:
+        with pytest.raises(NotImplementedError, match="basic_parallel"):
+            compile_plan(net, method=Method.BASIC_PARALLEL,
+                         use_pallas=use_pallas)
+    else:
+        compile_plan(net, method=Method.BASIC_PARALLEL,
+                     use_pallas=use_pallas)
+    compile_plan(net, method=SIMD, use_pallas=True)  # main path plans
+
+
 @pytest.mark.parametrize("net_name", ["lenet5", "cifar10", "alexnet"])
 def test_plan_steps_fully_resolved(net_name):
     """Every step carries resolved input/output shapes; fused steps carry

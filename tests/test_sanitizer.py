@@ -124,9 +124,10 @@ def test_k101_index_map_offset():
 
 
 def test_k101_body_load():
-    """A pl.ds(1, 1) slice on the size-1 frame axis reads past it."""
-    sources = _mutate("x = x_ref[0]", "x = x_ref[pl.ds(1, 1)][0]",
-                      count=1)  # first hit: the basic_simd kernel body
+    """Starting the basic_simd kernel's strided patch load one row late
+    reads past the input band's last row."""
+    sources = _mutate("patch = x_ref[pl.ds(i, ohh, stride=sy)",
+                      "patch = x_ref[pl.ds(i + 1, ohh, stride=sy)")
     f, _ = sanitize_conv2d((2, 28, 28, 8), (5, 5, 8, 16), im2col=False,
                            sources=sources)
     assert "K101" in _rules(f)

@@ -35,7 +35,7 @@ from repro.analysis.findings import (
     findings_markdown,
 )
 from repro.analysis.verifier import _BANDED_METHODS, step_band_params
-from repro.core.fusion import _ADVANCED_OC_BLOCK, IM2COL_METHODS
+from repro.core.fusion import ADVANCED_OC_BLOCK, IM2COL_METHODS
 from repro.core.methods import Method
 from repro.core.netdefs import NETWORKS
 from repro.core.plan import compile_plan
@@ -83,7 +83,7 @@ def sanitize_step(plan, step, label: str):
         im2col = step.method in IM2COL_METHODS
         kw_extra = {}
         if im2col:
-            kw_extra["oc_block"] = _ADVANCED_OC_BLOCK[step.method]
+            kw_extra["oc_block"] = ADVANCED_OC_BLOCK
         return sanitizer.sanitize_conv2d(
             (BATCH, h, w, cp), (spec.kernel[0], spec.kernel[1], cp,
                                 spec.out_channels),
@@ -97,7 +97,7 @@ def sanitize_step(plan, step, label: str):
         im2col = step.method in IM2COL_METHODS
         kw_extra = {}
         if im2col:
-            kw_extra["oc_block"] = _ADVANCED_OC_BLOCK[step.method]
+            kw_extra["oc_block"] = ADVANCED_OC_BLOCK
         kw = step.kwargs or {}
         return sanitizer.sanitize_conv2d(
             (BATCH, h, w, cp), (cv.kernel[0], cv.kernel[1], cp,

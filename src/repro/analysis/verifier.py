@@ -333,7 +333,7 @@ def verify_plan(plan: ExecutionPlan, net: Optional[NetworkDef] = None,
     shapes = infer_param_shapes(net)
     findings: List[Finding] = []
     for idx, step in enumerate(plan.steps):
-        label = f"step{idx}:{'+'.join(step.names)}"
+        label = f"step{idx}:{step.label}"
         findings += _shape_findings(step, label, cur, shapes)
         geo, equalized = step_band_params(plan, step)
         if geo is not None:

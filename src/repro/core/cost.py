@@ -375,7 +375,7 @@ def step_resources(plan: ExecutionPlan, step: PlanStep,
     # deferred: analysis imports core.plan at its top level
     from repro.analysis.verifier import step_band_params
 
-    label = "+".join(step.names)
+    label = step.label
     if step.kind in ("fused", "chain"):
         geo, _ = step_band_params(plan, step)
         return replace(

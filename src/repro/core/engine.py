@@ -33,13 +33,15 @@ is set, else as an XLA ``reduce_window``; LRN is a single channel-axis
 """
 from __future__ import annotations
 
+import re
 import time
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.findings import Finding, PlanVerificationError
 from repro.core.methods import Method, conv2d
@@ -108,6 +110,41 @@ class _KnobDict(dict):
 
 
 _UNSET = object()
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+
+
+def program_steps(hlo_text: str, labels: Iterable[str]) -> Dict[str, str]:
+    """``{instruction: step label}`` for every instruction of the entry
+    computation of a compiled program's text (``compiled.as_text()``):
+    the first component of its ``op_name`` that is one of ``labels``,
+    or ``""`` where none is (parameters, and the copies that layout
+    assignment inserts carry no step's scope).  Names are bare
+    (``conv2d.3``), as a profiler trace's operation text starts."""
+    labels = set(labels)
+    out: Dict[str, str] = {}
+    in_entry = False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY "):
+            in_entry = True
+        elif in_entry:
+            if line.startswith("}"):
+                break
+            m = _INSTRUCTION.match(line)
+            if m:
+                op = _OP_NAME.search(line)
+                scopes = op.group(1).split("/") if op else ()
+                out[m.group(1)] = next((p for p in scopes if p in labels), "")
+    return out
+
+
+def pad_batch(x, bucket: int):
+    """``x`` with zero frames appended up to ``bucket`` rows."""
+    if x.shape[0] == bucket:
+        return x
+    pad = jnp.zeros((bucket - x.shape[0], *x.shape[1:]), x.dtype)
+    return jnp.concatenate([x, pad], axis=0)
 
 
 def _knob(name: str):
@@ -340,7 +377,15 @@ class CNNEngine:
     def _bucket_jit(self, fuse: bool, bucket: int):
         key = (fuse, bucket)
         if key not in self._bucket_jits:
-            self._bucket_jits[key] = jax.jit(partial(self.forward, fuse=fuse))
+            forward = partial(self.forward, fuse=fuse)
+
+            def run(params, x):
+                return forward(params, x)
+
+            # the program's name: a trace's module line says which
+            # bucket ran
+            run.__name__ = run.__qualname__ = f"forward_b{bucket}"
+            self._bucket_jits[key] = jax.jit(run)
             self._bucket_compiles += 1
         return self._bucket_jits[key]
 
@@ -350,15 +395,33 @@ class CNNEngine:
         bucket's memoized jitted plan, slice the real rows back out.
         Arbitrary request batch sizes hit at most ``log2(max_batch)+1``
         compiled variants — the steady-state serving path (``CNNServer``)
-        never recompiles once its buckets are warm."""
+        never recompiles once its buckets are warm.  Under a profiler the
+        call is the host span ``cnn.forward_batched`` (arguments ``n``
+        and ``bucket``), the zero-padding ``cnn.pad`` inside it."""
         use_fuse = self.fuse_pool if fuse is None else bool(fuse)
         n = x.shape[0]
         bucket = self.batch_bucket(n)
-        fn = self._bucket_jit(use_fuse, bucket)
-        if bucket != n:
-            pad = jnp.zeros((bucket - n, *x.shape[1:]), x.dtype)
-            x = jnp.concatenate([x, pad], axis=0)
-        return fn(params, x)[:n]
+        with TraceAnnotation("cnn.forward_batched", n=n, bucket=bucket):
+            fn = self._bucket_jit(use_fuse, bucket)
+            if bucket != n:
+                with TraceAnnotation("cnn.pad"):
+                    x = pad_batch(x, bucket)
+            return fn(params, x)[:n]
+
+    def op_steps(self, params, x,
+                 fuse: Optional[bool] = None) -> Dict[str, str]:
+        """Which plan step each device operation of ``forward_batched``
+        on a batch shaped like ``x`` belongs to: ``program_steps`` of the
+        bucket's compiled program, whose instruction names are those of
+        the operations in a profiler trace of it.  The program is the
+        one ``forward_batched`` runs, taken from JAX's caches: after a
+        warm call this compiles nothing."""
+        use_fuse = self.fuse_pool if fuse is None else bool(fuse)
+        bucket = self.batch_bucket(x.shape[0])
+        compiled = self._bucket_jit(use_fuse, bucket).lower(
+            params, pad_batch(x, bucket)).compile()
+        return program_steps(compiled.as_text(),
+                             [s.label for s in self.plan(use_fuse).steps])
 
     def bucket_stats(self) -> dict:
         """Bucketed-jit cache introspection: the live (fuse, bucket)

@@ -162,6 +162,14 @@ class PlanStep:
     d_in: Optional[int] = None                # fc input features
     kwargs: Optional[Mapping] = None          # fused/chain tail constants
 
+    @property
+    def label(self) -> str:
+        """The step's name, ``"+".join(names)`` (``conv1+pool1+norm1``):
+        ``execute`` runs the step under ``jax.named_scope(label)``, so
+        every operation of the compiled program names its step in its
+        ``op_name`` (see ``CNNEngine.op_steps``)."""
+        return "+".join(self.names)
+
 
 def _lrn_kwargs(lrn: Optional[LayerSpec]) -> Dict:
     return dict(
@@ -305,9 +313,12 @@ class ExecutionPlan:
 
     def execute(self, params, x, collect: Optional[dict] = None):
         """x: [N, C, H, W].  A thin loop over the step executors — every
-        fusion/folding/shape decision was resolved at compile time."""
+        fusion/folding/shape decision was resolved at compile time.  Each
+        step traces inside ``jax.named_scope(step.label)``, which costs
+        nothing at run time."""
         for step in self.steps:
-            x = _EXECUTORS[step.kind](self, step, params, x)
+            with jax.named_scope(step.label):
+                x = _EXECUTORS[step.kind](self, step, params, x)
             if collect is not None:
                 for n in step.names:
                     collect[n] = x

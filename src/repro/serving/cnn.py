@@ -36,9 +36,11 @@ in **dynamic batches**:
   dynamic batch it rode in.
 
 ``stats()`` reports the serving-scale numbers the benchmarks record
-(requests served, batches, p50/p95 latency, throughput over busy time)
-plus the robustness counters (shed/rejected/expired/retried/failed/
-degraded/breaker trips); ``health()`` snapshots the live state.  The
+(requests served, batches, busy time, p50/p95 latency and queue wait,
+p95 batch service time) plus the robustness counters (shed/rejected/
+expired/retried/failed/degraded/breaker trips); ``health()`` snapshots
+the live state.  Under a profiler each served batch is the host span
+``cnn.server.batch`` with its phases as children (``README.md``).  The
 clock, the backoff sleep, and the engine-fault schedule
 (``serving.faults``) are all injectable, so every recovery path is
 deterministic under test — no real sleeps anywhere.
@@ -53,8 +55,9 @@ from typing import (Callable, Deque, Dict, List, Optional, Sequence, Tuple,
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.core.engine import CNNEngine
+from repro.core.engine import CNNEngine, pad_batch
 from repro.serving.degrade import DegradeController
 from repro.serving.faults import FaultInjector, TransientEngineFault
 
@@ -230,6 +233,9 @@ class CNNServer:
         # distinct from _latencies_s so reset_stats keeps pressure
         # detection alive across bench warm-up resets
         self._recent_lat_s: Deque[float] = deque(maxlen=128)
+        # sequence number of the next served batch (the batch span's
+        # ``seq``); never reset
+        self._batch_seq = 0
         self.reset_stats()
 
     # -- client API -----------------------------------------------------------
@@ -339,20 +345,18 @@ class CNNServer:
         sub-batches keep the PARENT batch's bucket, so they reuse the
         same compiled executable and surviving rows stay byte-identical
         to a fault-free run (zero-pad rows are inert batchmates)."""
-        x = jnp.asarray(xs)
-        if x.shape[0] < bucket:
-            pad = jnp.zeros((bucket - x.shape[0], *x.shape[1:]), x.dtype)
-            x = jnp.concatenate([x, pad], axis=0)
-
         def call(arr):
             return self.engine.forward_batched(self.params, arr,
                                                fuse=self.fuse)
 
-        if self.fault_injector is not None:
-            probs = self.fault_injector(call, x, rids)
-        else:
-            probs = call(x)
-        return np.asarray(probs)[:len(rids)]
+        with TraceAnnotation("cnn.server.forward"):
+            x = pad_batch(jnp.asarray(xs), bucket)
+            if self.fault_injector is not None:
+                probs = self.fault_injector(call, x, rids)
+            else:
+                probs = call(x)
+        with TraceAnnotation("cnn.server.fetch"):
+            return np.asarray(probs)[:len(rids)]
 
     def _supervise(self, xs: np.ndarray, rids: List[int],
                    bucket: int) -> List[Tuple[str, object]]:
@@ -420,49 +424,65 @@ class CNNServer:
             self._observe_degrade()
             return results
         take = min(len(self._pending), self.max_batch)
-        batch = [self._pending.popleft() for _ in range(take)]
-        xs = np.stack([np.asarray(r.image, np.float32)
-                       for r, _, _ in batch])
+        with TraceAnnotation("cnn.server.batch", seq=self._batch_seq,
+                             size=take):
+            self._batch_seq += 1
+            results += self._serve(
+                [self._pending.popleft() for _ in range(take)])
+        self._observe_degrade()
+        return results
+
+    def _serve(self, batch: List[_Pending]) -> List[Result]:
+        """Run one batch taken off the queue and resolve its requests."""
+        take = len(batch)
+        with TraceAnnotation("cnn.server.stack"):
+            xs = np.stack([np.asarray(r.image, np.float32)
+                           for r, _, _ in batch])
         rids = [r.rid for r, _, _ in batch]
         bucket = CNNEngine.batch_bucket(take)
         t0 = self.clock()
         rows = self._supervise(xs, rids, bucket)
         t1 = self.clock()
-        self._busy_s += t1 - t0
-        self._batch_sizes.append(take)
         dt = t1 - t0
+        self._batch_sizes.append(take)
+        self._service_s.append(dt)
+        self._queue_wait_s += [t0 - t_sub for _, t_sub, _ in batch]
         self._service_ewma_s = (dt if self._service_ewma_s == 0.0
                                 else 0.5 * self._service_ewma_s + 0.5 * dt)
+        results: List[Result] = []
         any_failed = False
-        for (req, t_sub, _), (status, payload) in zip(batch, rows):
-            res: Result
-            if status == "fail":
-                res = FailedResult(
-                    rid=req.rid, error="engine_fault", detail=str(payload),
-                    latency_s=t1 - t_sub, batch_size=take, bucket=bucket)
-            else:
-                p = np.asarray(payload)
-                if not np.all(np.isfinite(p)):
+        with TraceAnnotation("cnn.server.topk"):
+            for (req, t_sub, _), (status, payload) in zip(batch, rows):
+                res: Result
+                if status == "fail":
                     res = FailedResult(
-                        rid=req.rid, error="non_finite_output",
-                        detail="output row contains NaN/Inf",
-                        latency_s=t1 - t_sub, batch_size=take, bucket=bucket)
+                        rid=req.rid, error="engine_fault",
+                        detail=str(payload), latency_s=t1 - t_sub,
+                        batch_size=take, bucket=bucket)
                 else:
-                    k = max(1, min(req.top_k, p.shape[-1]))
-                    top = np.argsort(-p, kind="stable")[:k]
-                    res = ImageResult(
-                        rid=req.rid, top_indices=[int(j) for j in top],
-                        top_probs=[float(p[j]) for j in top],
-                        latency_s=t1 - t_sub, batch_size=take, bucket=bucket)
-                    self._latencies_s.append(res.latency_s)
-                    self._recent_lat_s.append(res.latency_s)
-            if not res.ok:
-                any_failed = True
-                self._counters["failed"] += 1
-            self.done[req.rid] = res
-            results.append(res)
+                    p = np.asarray(payload)
+                    if not np.all(np.isfinite(p)):
+                        res = FailedResult(
+                            rid=req.rid, error="non_finite_output",
+                            detail="output row contains NaN/Inf",
+                            latency_s=t1 - t_sub, batch_size=take,
+                            bucket=bucket)
+                    else:
+                        k = max(1, min(req.top_k, p.shape[-1]))
+                        top = np.argsort(-p, kind="stable")[:k]
+                        res = ImageResult(
+                            rid=req.rid, top_indices=[int(j) for j in top],
+                            top_probs=[float(p[j]) for j in top],
+                            latency_s=t1 - t_sub, batch_size=take,
+                            bucket=bucket)
+                        self._latencies_s.append(res.latency_s)
+                        self._recent_lat_s.append(res.latency_s)
+                if not res.ok:
+                    any_failed = True
+                    self._counters["failed"] += 1
+                self.done[req.rid] = res
+                results.append(res)
         self._breaker_after_step(t1, any_failed)
-        self._observe_degrade()
         return results
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[int, Result]:
@@ -522,28 +542,30 @@ class CNNServer:
 
     # -- stats / health ---------------------------------------------------------
     def reset_stats(self) -> None:
-        """Zero the latency/throughput accumulators and robustness
+        """Zero the latency, queue-wait and service accumulators and the
         counters (results in ``done`` and live state — breaker,
         degradation rung — are kept; benches call this after warm-up so
         compile time never pollutes the serving numbers)."""
         self._latencies_s: List[float] = []
         self._batch_sizes: List[int] = []
-        self._busy_s = 0.0
+        self._queue_wait_s: List[float] = []
+        self._service_s: List[float] = []
         self._counters: Dict[str, int] = {k: 0 for k in _COUNTERS}
 
     def stats(self) -> dict:
         """Serving-scale numbers since the last ``reset_stats()``:
-        requests/batches served, mean batch size, p50/p95 submit→done
-        latency (us), throughput (requests per second of server busy
-        time — ``0.0`` when no busy time was accrued, never ``inf``),
-        and the robustness counters."""
+        requests/batches served, mean batch size, busy seconds, p50/p95
+        submit→done latency (us), p50/p95 queue wait (submit to the
+        start of its batch's service, every request of a served batch,
+        us), p95 batch service time (us), and the robustness
+        counters."""
         served = len(self._latencies_s)
         out = {
             "served": served,
             "batches": len(self._batch_sizes),
             "mean_batch": (float(np.mean(self._batch_sizes))
                            if self._batch_sizes else 0.0),
-            "busy_s": self._busy_s,
+            "busy_s": float(sum(self._service_s)),
             "buckets": self.engine.bucket_stats()["buckets"],
             **self._counters,
         }
@@ -551,8 +573,12 @@ class CNNServer:
             lat = np.asarray(self._latencies_s)
             out["p50_latency_us"] = float(np.percentile(lat, 50) * 1e6)
             out["p95_latency_us"] = float(np.percentile(lat, 95) * 1e6)
-            out["throughput_rps"] = (served / self._busy_s
-                                     if self._busy_s > 0 else 0.0)
+        if self._service_s:
+            wait = np.asarray(self._queue_wait_s)
+            out["p50_queue_wait_us"] = float(np.percentile(wait, 50) * 1e6)
+            out["p95_queue_wait_us"] = float(np.percentile(wait, 95) * 1e6)
+            out["p95_service_us"] = float(
+                np.percentile(np.asarray(self._service_s), 95) * 1e6)
         return out
 
     def health(self) -> dict:

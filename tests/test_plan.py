@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import pytest
 
 import repro.core.plan as plan_mod
-from repro.core.engine import CNNEngine
+from repro.core.engine import CNNEngine, program_steps
 from repro.core.fusion import fusion_summary
 from repro.core.methods import Method
 from repro.core.netdefs import NETWORKS, LayerSpec, NetworkDef
@@ -203,6 +203,69 @@ def test_bucketed_cache_compile_bound():
     # and the sliced result agrees with the eager per-plan forward
     eager = eng.forward(params, xs[:3])
     assert jnp.max(jnp.abs(a - eager)) < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# every device operation under its plan step
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("net_name", ["lenet5", "cifar10"])
+def test_op_steps_maps_the_bucket_program_to_plan_steps(net_name):
+    """``op_steps`` reads the program ``forward_batched`` runs, compiling
+    nothing more: every step that does work names some instruction,
+    each mapped name is an instruction of the entry computation, and
+    the program is named after its bucket."""
+    net = NETWORKS[net_name]()
+    eng = CNNEngine(net, method=SIMD, use_pallas=False)
+    params = eng.init(jax.random.PRNGKey(0))
+    x = jnp.ones((3, *net.input_shape), jnp.float32)
+    eng.forward_batched(params, x)
+    compiles = []
+
+    def on(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        steps = eng.op_steps(params, x)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert compiles == [] and eng.bucket_stats()["compiles"] == 1
+    labels = {s.label for s in eng.plan().steps}
+    # a flatten compiles to a bitcast, or folds into its neighbour's
+    # fusion: no operation of its own carries its scope
+    working = {s.label for s in eng.plan().steps if s.kind != "flatten"}
+    assert working <= set(steps.values()) <= labels | {""}
+    assert "prob" in working  # the softmax is a step of its own
+    text = eng._bucket_jit(True, 4).lower(
+        params, jnp.ones((4, *net.input_shape))).compile().as_text()
+    assert text.startswith("HloModule jit_forward_b4")
+    entry = text[text.index("\nENTRY "):].split("\n}")[0]
+    for name in steps:
+        assert f"%{name} = " in entry, name
+    assert steps["x.1"] == ""  # the input parameter is under no step
+
+
+def test_program_steps_reads_the_entry_computation_only():
+    text = "\n".join([
+        "HloModule jit_forward_b2",
+        "%fused (p: f32[2]) -> f32[2] {",
+        '  %inner = f32[2]{0} negate(%p), metadata={op_name="jit(f)/fc1/neg"}',
+        "}",
+        "ENTRY %main (x: f32[2]) -> f32[2] {",
+        '  %x.1 = f32[2]{0} parameter(0), metadata={op_name="x"}',
+        "  %copy.3 = f32[2]{0} copy(%x.1)",
+        '  %conv2d.3 = f32[2]{0} custom-call(%copy.3), custom_call_target='
+        '"tpu_custom_call", metadata={op_name="jit(forward_b2)/'
+        'conv1+pool1/jit(run)/pallas_call"}',
+        '  ROOT %fusion = f32[2]{0} fusion(%conv2d.3), kind=kLoop, '
+        'calls=%fused, metadata={op_name="jit(forward_b2)/fc1/neg"}',
+        "}",
+    ])
+    assert program_steps(text, ["conv1+pool1", "fc1", "prob"]) == {
+        "x.1": "", "copy.3": "", "conv2d.3": "conv1+pool1", "fusion": "fc1"}
 
 
 # ---------------------------------------------------------------------------

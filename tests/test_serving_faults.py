@@ -393,15 +393,97 @@ def test_run_until_drained_raises_when_wedged(lenet):
 
 
 def test_stats_throughput_zero_not_inf(lenet):
-    """Under a frozen clock busy_s is 0 — throughput must report 0.0,
-    never inf."""
+    """Under a frozen clock busy_s is 0 — the time numbers read 0.0,
+    never inf or nan, and no rate over busy time is reported."""
     net, eng, params, imgs = lenet
     srv = _server(eng, params, max_batch=2, max_delay_s=0.0)
     _submit(srv, imgs, range(2))
     srv.run_until_drained()
     s = srv.stats()
     assert s["served"] == 2 and s["busy_s"] == 0.0
-    assert s["throughput_rps"] == 0.0
+    assert s["p50_queue_wait_us"] == s["p95_queue_wait_us"] == 0.0
+    assert s["p95_service_us"] == 0.0
+    assert "throughput_rps" not in s
+
+
+def test_queue_wait_and_service_time_under_the_injected_clock(lenet):
+    """Queue wait runs from submit to the start of the batch's service,
+    service from that start to the batch's end: their sum is each
+    request's latency."""
+    net, eng, params, imgs = lenet
+    clock = FakeClock()
+    inj = FaultInjector(FaultScript(latency_spikes={0: 0.5, 1: 0.25}),
+                        advance=clock.advance)
+    srv = _server(eng, params, max_batch=2, max_delay_s=10.0, clock=clock,
+                  fault_injector=inj)
+    _submit(srv, imgs, [0])                  # t = 0
+    clock.t = 1.0
+    _submit(srv, imgs, [1])                  # t = 1; the batch is full
+    clock.t = 3.0
+    srv.step()                               # service 3.0 -> 3.5
+    _submit(srv, imgs, [2])                  # t = 3.5
+    clock.t = 4.0
+    srv.step(force=True)                     # service 4.0 -> 4.25
+    s = srv.stats()
+    waits = [3.0, 2.0, 0.5]
+    assert s["batches"] == 2 and s["served"] == 3
+    assert s["p50_queue_wait_us"] == pytest.approx(
+        np.percentile(waits, 50) * 1e6)
+    assert s["p95_queue_wait_us"] == pytest.approx(
+        np.percentile(waits, 95) * 1e6)
+    assert s["p95_service_us"] == pytest.approx(
+        np.percentile([0.5, 0.25], 95) * 1e6)
+    assert s["busy_s"] == pytest.approx(0.75)
+    lat = sorted(srv.done[r].latency_s for r in range(3))
+    assert lat == pytest.approx(sorted([3.5, 2.5, 0.75]))
+    srv.reset_stats()
+    assert "p95_queue_wait_us" not in srv.stats()
+
+
+def test_every_served_batch_is_a_host_span_under_the_profiler(lenet,
+                                                               tmp_path):
+    """The server's and the engine's spans reach the profiler's host
+    plane, nested as documented, and none is a benchmark span."""
+    from jax.profiler import ProfileData
+
+    net, eng, params, imgs = lenet
+    srv = _server(eng, params, max_batch=4, max_delay_s=0.0)
+    _submit(srv, imgs, range(3))             # a ragged batch: padded
+    srv.run_until_drained()                  # warm, off the trace
+    _submit(srv, imgs, range(10, 13))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.step()
+        np.asarray(eng.forward_batched(params, jnp.asarray(imgs[:3])))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    spans = {}
+    for name, s, e, stats in events:
+        if name.startswith("cnn."):
+            spans.setdefault(name, []).append((s, e, stats))
+    assert set(spans) == {
+        "cnn.server.batch", "cnn.server.stack", "cnn.server.forward",
+        "cnn.server.fetch", "cnn.server.topk", "cnn.forward_batched",
+        "cnn.pad"}
+    assert not [n for n, *_ in events if n.startswith("bench.")]
+    ((b0, b1, batch),) = spans["cnn.server.batch"]
+    assert batch == {"seq": 1, "size": 3}
+    for child in ("cnn.server.stack", "cnn.server.forward",
+                  "cnn.server.fetch", "cnn.server.topk"):
+        ((s, e, _),) = spans[child]
+        assert b0 <= s <= e <= b1, child
+    # the server pads to the bucket itself; the direct call pads inside
+    # forward_batched
+    calls = sorted(spans["cnn.forward_batched"])
+    assert [c[2] for c in calls] == [{"n": 4, "bucket": 4},
+                                     {"n": 3, "bucket": 4}]
+    ((p0, p1, _),) = spans["cnn.pad"]
+    assert calls[1][0] <= p0 <= p1 <= calls[1][1]
 
 
 # ---------------------------------------------------------------------------

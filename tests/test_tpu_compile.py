@@ -94,3 +94,30 @@ def test_main_path_kernel_compiles_for_v5e(name, one_chip):
     lowered = jax.jit(fn).lower(*args)
     assert "tpu_custom_call" in lowered.as_text()  # a Mosaic kernel, not XLA
     lowered.compile()  # raises what the chip's compiler would refuse
+
+
+def test_bucket_program_puts_each_kernel_under_its_plan_step(one_chip,
+                                                             monkeypatch):
+    """cifar10's whole bucket program, compiled for v5e: every Pallas
+    kernel and every other operation of a step carries that step's
+    scope in its ``op_name``, so ``op_steps`` maps the kernels the trace
+    names (``conv2d.3``, ``matmul_fused.2``) to their plan steps."""
+    from repro.core.engine import CNNEngine
+    from repro.core.netdefs import NETWORKS
+    from repro.kernels.pool2d import ops as pool_ops
+
+    for mod in (conv_ops, mm_ops, pool_ops):
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    eng = CNNEngine(NETWORKS["cifar10"](), use_pallas=True)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(eng.init, jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((BATCH, *eng.net.input_shape), jnp.float32,
+                             sharding=one_chip)
+    steps = eng.op_steps(params, x)
+    kernels = {n: label for n, label in steps.items()
+               if n.startswith(("conv2d.", "matmul_fused."))}
+    assert sorted(kernels.values()) == [
+        "conv1+pool1", "conv2+pool2", "conv3+pool3", "fc1", "fc2"]
+    assert steps["x.1"] == ""
+    assert {s.label for s in eng.plan().steps} == set(steps.values()) - {""}

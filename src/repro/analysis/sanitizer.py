@@ -1516,6 +1516,27 @@ class Interp:
                 joined = _arr_binop(joined, a.like(shape=joined.shape), self)
             base[axis] = total
             return joined.like(shape=tuple(base), mask=None)
+        if name == "stack":
+            seq = [a for a in self.iterate(args[0])]
+            axis = kwargs.get("axis", args[1] if len(args) > 1 else 0)
+            if not seq or not all(isinstance(a, AArray) for a in seq):
+                raise Unsupported("stack of non-arrays")
+            if any(a.shape != seq[0].shape for a in seq):
+                self.finding("K100", "stack shape mismatch "
+                                     f"{[a.shape for a in seq]}")
+            joined = seq[0]
+            for a in seq[1:]:
+                joined = _arr_binop(joined, a, self)
+            shape = list(seq[0].shape)
+            shape.insert(axis % (len(shape) + 1), len(seq))
+            return joined.like(shape=tuple(shape), mask=None)
+        if name == "transpose":
+            arr, axes = args[0], tuple(args[1])
+            if not isinstance(arr, AArray):
+                raise Unsupported("jnp.transpose of non-array")
+            if sorted(axes) != list(range(arr.ndim)):
+                raise Unsupported(f"jnp.transpose axes {axes!r}")
+            return arr.like(shape=tuple(arr.shape[a] for a in axes))
         if name == "dot":
             a, b = args[0], args[1]
             if not (isinstance(a, AArray) and isinstance(b, AArray)):
@@ -1938,25 +1959,23 @@ KERNEL_SOURCES = {"conv2d": "conv2d/kernels.py",
 _PALLAS_ALIASES = {"pallas": "pl", "tpu": "pltpu"}
 
 
-def _i_plan_oh_tiles(xp, oh, kh, kw, sy, oh_block, ow, oc_block,
+def _i_plan_oh_tiles(shape, oh, kh, kw, sy, oh_block, ow, oc_block,
                      im2col=True):
-    """Phase-A answer for the un-fused band planner (pads abstractly)."""
-    n, hp, wp, c = xp.shape
+    """Phase-A answer for the un-fused band planner."""
+    n, hp, wp, c = shape
     ohb = _a_resolve_oh(oh, ow, wp, c, kh, kw, sy, oc_block, oh_block,
                         im2col=im2col)
     n_tiles = _ceil_div(oh, ohb)
     band = _a_band(ohb, kh, sy)
     hp_need = (n_tiles - 1) * ohb * sy + band
-    if hp_need > hp:
-        xp = xp.like(shape=(n, hp_need, wp, c))
-    return xp, ohb, n_tiles, band
+    return max(0, hp_need - hp), ohb, n_tiles, band
 
 
-def _i_plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
+def _i_plan_pool_tiles(shape, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
                        im2col=True, oc_halo=0):
     """Phase-A answer for the fused conv+pool band planner."""
     pkh, pkw, psy, psx = pool
-    n, hp, wp, c = xp.shape
+    n, hp, wp, c = shape
     ph, pw = (oh - pkh) // psy + 1, (ow - pkw) // psx + 1
     if ph < 1 or pw < 1:
         raise KernelRaise(
@@ -1969,9 +1988,8 @@ def _i_plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
     band = _a_band(cband, kh, sy)
     row_step = phb * psy * sy
     hp_need = (n_tiles - 1) * row_step + band
-    if hp_need > hp:
-        xp = xp.like(shape=(n, hp_need, wp, c))
-    return xp, phb, n_tiles, band, cband, ph, pw, row_step
+    return (max(0, hp_need - hp), phb, n_tiles, band, cband, ph, pw,
+            row_step)
 
 
 def _i_pool_out_size(size, k, stride):

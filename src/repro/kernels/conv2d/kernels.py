@@ -445,25 +445,23 @@ def conv2d_basic_parallel(x, w, b, stride=(1, 1), padding=(0, 0), relu=False,
 # ---------------------------------------------------------------------------
 
 
-def _plan_oh_tiles(xp, oh, kh, kw, sy, oh_block, ow, oc_block,
+def _plan_oh_tiles(shape, oh, kh, kw, sy, oh_block, ow, oc_block,
                    im2col=True):
-    """Resolve the band size and pad the input so every band is full.
+    """Resolve the band size from the padded input's ``shape``.
 
-    Returns ``(xp, ohb, n_tiles, band)`` where ``xp`` has enough bottom
-    zero-rows that the last band — starting at ``(n_tiles-1)*ohb*sy`` and
-    spanning ``band`` rows — stays in bounds; the surplus output rows are
-    sliced off by the caller.
+    Returns ``(bottom, ohb, n_tiles, band)`` where ``bottom`` is the
+    zero rows to add under the input so the last band — starting at
+    ``(n_tiles-1)*ohb*sy`` and spanning ``band`` rows — stays in bounds;
+    the surplus output rows are sliced off by the caller.
     """
-    n, hp, wp, c = xp.shape
+    n, hp, wp, c = shape
     ohb = resolve_oh_block(oh, ow, wp, c, kh, kw, sy, oc_block, oh_block,
                            im2col=im2col)
     n_tiles = -(-oh // ohb)
     band = _band_rows(ohb, kh, sy)
     _, in_iv = band_intervals(n_tiles, ohb, oh, ohb * sy, band)
     hp_need = in_iv[-1][0] + band
-    if hp_need > hp:
-        xp = jnp.pad(xp, ((0, 0), (0, hp_need - hp), (0, 0), (0, 0)))
-    return xp, ohb, n_tiles, band
+    return max(0, hp_need - hp), ohb, n_tiles, band
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +469,7 @@ def _plan_oh_tiles(xp, oh, kh, kw, sy, oh_block, ow, oc_block,
 # ---------------------------------------------------------------------------
 
 
-def _plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
+def _plan_pool_tiles(shape, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
                      im2col=True, oc_halo=0):
     """Band geometry for a fused conv+pool cell.
 
@@ -481,11 +479,12 @@ def _plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
     pkh`` conv rows per cell), then *equalizes* the bands — ``ph_block``
     is re-snapped to ``ceil(ph / n_tiles)`` so the last band covers its
     fair share instead of being a ragged remainder that still fetches a
-    full band of (mostly pad) input rows.  Pads the input so every band
-    stays in bounds.  Returns ``(xp, ph_block, n_tiles, band, cband, ph,
-    pw, row_step)`` where ``band`` is input rows per cell, ``cband`` conv
-    rows per cell, ``(ph, pw)`` the pooled output size, and ``row_step``
-    the input-row stride between consecutive bands.
+    full band of (mostly pad) input rows.  ``shape`` is the padded
+    input's.  Returns ``(bottom, ph_block, n_tiles, band, cband, ph, pw,
+    row_step)`` where ``bottom`` is the zero rows to add under the input
+    so every band stays in bounds, ``band`` input rows per cell,
+    ``cband`` conv rows per cell, ``(ph, pw)`` the pooled output size,
+    and ``row_step`` the input-row stride between consecutive bands.
 
     Floor: a fused cell can never hold fewer than one pool window of conv
     rows, so a one-pooled-row cell may exceed the *soft*
@@ -495,7 +494,7 @@ def _plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
     working-set check (``repro.core.fusion``).
     """
     pkh, pkw, psy, psx = pool
-    n, hp, wp, c = xp.shape
+    n, hp, wp, c = shape
     ph, pw = (oh - pkh) // psy + 1, (ow - pkw) // psx + 1
     if ph < 1 or pw < 1:
         raise ValueError(
@@ -508,9 +507,7 @@ def _plan_pool_tiles(xp, oh, ow, kh, kw, sy, oh_block, oc_block, pool,
     row_step = phb * psy * sy
     _, in_iv = band_intervals(n_tiles, phb, ph, row_step, band)
     hp_need = in_iv[-1][0] + band
-    if hp_need > hp:
-        xp = jnp.pad(xp, ((0, 0), (0, hp_need - hp), (0, 0), (0, 0)))
-    return xp, phb, n_tiles, band, cband, ph, pw, row_step
+    return max(0, hp_need - hp), phb, n_tiles, band, cband, ph, pw, row_step
 
 
 def pool_stage_shapes(rows, ow, width):
@@ -622,7 +619,7 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
     kh, kw, _, oc = w_hwio.shape
     sy, sx = stride
     py, px = padding
-    xp = jnp.pad(x_nhwc, ((0, 0), (py, py), (px, px), (0, 0)))
+    hp, wp = h + 2 * py, wd + 2 * px
     oh, ow = _out_size(h, kh, sy, py), _out_size(wd, kw, sx, px)
     if lrn is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
@@ -631,20 +628,20 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         # activation stays in VMEM
         pkh, pkw = pool_kernel
         psy, psx = pool_stride if pool_stride is not None else pool_kernel
-        xp, phb, n_tiles, band, cband, ph, pw, row_step = _plan_pool_tiles(
-            xp, oh, ow, kh, kw, sy, oh_block, oc,
-            (pkh, pkw, psy, psx), im2col=False)
+        bottom, phb, n_tiles, band, cband, ph, pw, row_step = \
+            _plan_pool_tiles((n, hp, wp, c), oh, ow, kh, kw, sy, oh_block,
+                             oc, (pkh, pkw, psy, psx), im2col=False)
         pool = (pkh, pkw, psy, psx, pool_kind, pool_relu, ow)
         out_rows, out_cols = phb, pw
         scratch = pool_stage_shapes(cband, ow, oc)
     else:
-        xp, ohb, n_tiles, band = _plan_oh_tiles(xp, oh, kh, kw, sy, oh_block,
-                                                ow, oc, im2col=False)
+        bottom, ohb, n_tiles, band = _plan_oh_tiles(
+            (n, hp, wp, c), oh, kh, kw, sy, oh_block, ow, oc, im2col=False)
         pool = None
         row_step = ohb * sy
         out_rows, out_cols = ohb, ow
         scratch = []
-    wp = xp.shape[2]
+    xp = jnp.pad(x_nhwc, ((0, 0), (py, py + bottom), (px, px), (0, 0)))
     kern = functools.partial(_basic_simd_kernel, kh=kh, kw=kw, sy=sy, sx=sx,
                              relu=relu, pool=pool, lrn=lrn)
     out = pl.pallas_call(
@@ -760,20 +757,32 @@ def _advanced_simd_carry_kernel(x_ref, w_ref, b_ref, o_ref, c_ref, *p_refs,
     c_ref[...] = jax.lax.slice_in_dim(fresh, r_rows - k_rows, r_rows, axis=0)
 
 
-def _pack_width_taps(xp, wmat, kh, kw, sx, ow):
-    """Unfold a conv's kernel-width taps into the lane axis: column ``q``
-    of the result holds input columns ``q*sx + j`` (``j < kw``) side by
-    side, zero-padded to ``LANES`` channels, and the im2col weight rows
-    are reordered to match.  The in-kernel patch build then joins ``kh``
-    lane-aligned pieces instead of ``kh*kw`` narrow ones, which Mosaic
-    would lower to one lane shuffle per piece and sublane tile (AlexNet
-    conv1: 121 pieces of 8 lanes)."""
-    n, hp, _, c = xp.shape
-    taps = [jax.lax.slice(xp, (0, 0, j, 0),
-                          (n, hp, j + (ow - 1) * sx + 1, c), (1, 1, sx, 1))
-            for j in range(kw)]
-    xu = jnp.pad(jnp.concatenate(taps, axis=-1),
-                 ((0, 0), (0, 0), (0, 0), (0, LANES - kw * c)))
+def _pack_width_taps(x_nhwc, wmat, rows, px, kh, kw, sx, ow):
+    """The conv operand with each kernel row's width taps unfolded into
+    the lane axis: column ``q`` of the result holds padded-input columns
+    ``q*sx + j`` (``j < kw``) side by side, tap-major, zero-padded to
+    ``LANES`` channels, and the im2col weight rows are reordered to
+    match.  ``rows`` is the ``(top, bottom)`` zero rows the band plan
+    needs and ``px`` the conv's width padding.  The in-kernel patch
+    build then joins ``kh`` lane-aligned pieces instead of ``kh*kw``
+    narrow ones, which Mosaic would lower to one lane shuffle per piece
+    and sublane tile (AlexNet conv1: 121 pieces of 8 lanes).
+
+    On a TPU an HBM array whose minor axis holds C = 3 or 8 channels is
+    tiled to 128 lanes, so the taps are cut with W on the lane axis:
+    under jit the first transpose cancels the caller's NCHW→NHWC swap.
+    They are stacked on a new axis rather than joined along C, which
+    would let XLA's layout assignment put C back on the lanes to make
+    the final transpose free."""
+    n, _, _, c = x_nhwc.shape
+    xp = jnp.pad(jnp.transpose(x_nhwc, (0, 3, 1, 2)),
+                 ((0, 0), (0, 0), rows, (px, px)))
+    hp = xp.shape[2]
+    taps = jnp.stack(
+        [jax.lax.slice(xp, (0, 0, 0, j), (n, c, hp, j + (ow - 1) * sx + 1),
+                       (1, 1, 1, sx)) for j in range(kw)], axis=-1)
+    xu = jnp.transpose(taps, (0, 2, 3, 4, 1)).reshape(n, hp, ow, kw * c)
+    xu = jnp.pad(xu, ((0, 0), (0, 0), (0, 0), (0, LANES - kw * c)))
     oc = wmat.shape[1]
     wu = jnp.pad(wmat.reshape(kh, kw * c, oc),
                  ((0, 0), (0, LANES - kw * c), (0, 0)))
@@ -790,7 +799,7 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
     kh, kw, _, oc = w_hwio.shape
     sy, sx = stride
     py, px = padding
-    xp = jnp.pad(x_nhwc, ((0, 0), (py, py), (px, px), (0, 0)))
+    hp, wp = h + 2 * py, wd + 2 * px
     oh, ow = _out_size(h, kh, sy, py), _out_size(wd, kw, sx, px)
     if lrn is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
@@ -805,7 +814,7 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
     # selects the halo dispatch below)
     if lrn is not None:
         ocb, oc_halo = resolve_lrn_ocb(oc, oc_block, lrn, lrn_oc_block, ow,
-                                       xp.shape[2], c, kh, kw, sy,
+                                       wp, c, kh, kw, sy,
                                        (pkh, pkw, psy, psx))
     else:
         ocb, oc_halo = min(oc_block, oc), 0
@@ -827,9 +836,9 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
     if pool_kernel is not None:
         # fused super-layer: each cell writes a pooled band, the conv
         # activation stays in VMEM
-        xp, phb, n_tiles, band, cband, ph, pw, row_step = _plan_pool_tiles(
-            xp, oh, ow, kh, kw, sy, oh_block, ocb, (pkh, pkw, psy, psx),
-            oc_halo=oc_halo)
+        bottom, phb, n_tiles, band, cband, ph, pw, row_step = \
+            _plan_pool_tiles((n, hp, wp, c), oh, ow, kh, kw, sy, oh_block,
+                             ocb, (pkh, pkw, psy, psx), oc_halo=oc_halo)
         pool = (pkh, pkw, psy, psx, pool_kind, pool_relu, ow)
         out_rows, out_cols = phb, pw
         carry = resolve_pool_carry(pool_carry, True, lrn,
@@ -837,32 +846,34 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         # the conv band the pool epilogue stages in VMEM
         scratch = pool_stage_shapes(cband, ow, ocb + oc_halo)
     else:
-        xp, ohb, n_tiles, band = _plan_oh_tiles(xp, oh, kh, kw, sy, oh_block,
-                                                ow, ocb)
+        bottom, ohb, n_tiles, band = _plan_oh_tiles(
+            (n, hp, wp, c), oh, kh, kw, sy, oh_block, ow, ocb)
         pool = None
         row_step = ohb * sy
         out_rows, out_cols = ohb, ow
         carry = False
         scratch = []
-    wp = xp.shape[2]
+    prepad = 0
     if carry:
         k_rows = pkh - psy        # conv rows carried between band steps
         r_rows = phb * psy        # fresh conv rows per band step
         band = (r_rows - 1) * sy + kh
         row_step = r_rows * sy
-        prepad = row_step - k_rows * sy
         # the zero prepad makes step 0 a pure seed band: its output block
         # pools prepad zeros (sliced off below) while its last K fresh
         # conv rows are conv rows [0, K) — step 1's carry.  The bottom
-        # rows _plan_pool_tiles already padded are exactly what the
-        # shifted bands need (the prepad algebra cancels to zero extra).
-        xp = jnp.pad(xp, ((0, 0), (prepad, 0), (0, 0), (0, 0)))
+        # rows _plan_pool_tiles asked for are exactly what the shifted
+        # bands need (the prepad algebra cancels to zero extra).
+        prepad = row_step - k_rows * sy
+    rows = (py + prepad, py + bottom)
     if kw > 1 and kw * c <= LANES:
         # a kernel row's taps fit one lane tile: unfold them host-side,
         # the cell then runs a (kh, 1) stride-(sy, 1) conv.  Row bands
         # are unchanged, so the geometry resolved above still holds.
-        xp, wmat = _pack_width_taps(xp, wmat, kh, kw, sx, ow)
+        xp, wmat = _pack_width_taps(x_nhwc, wmat, rows, px, kh, kw, sx, ow)
         wp, c, kw, sx = ow, LANES, 1, 1
+    else:
+        xp = jnp.pad(x_nhwc, ((0, 0), rows, (px, px), (0, 0)))
     if carry:
         oc_tiles = ocp // ocb
         kern = functools.partial(_advanced_simd_carry_kernel, kh=kh, kw=kw,

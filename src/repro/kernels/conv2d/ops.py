@@ -70,10 +70,10 @@ def conv2d(x, w, b, stride=(1, 1), padding=(0, 0), relu=False,
     if lrn_n is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
     lrn = (lrn_n, lrn_alpha, lrn_beta, lrn_k) if lrn_n is not None else None
-    # SIMD methods: dimension swapping + channel padding (§4.3)
-    xh = nchw_to_nhwc(x)
+    # SIMD methods: channel padding + dimension swapping (§4.3); C is
+    # padded while it is a major axis, where the pad is cheap
+    xh = nchw_to_nhwc(pad_axis(x, 1, SUBLANES)[0])
     wh = oihw_to_hwio(w)
-    xh, _ = pad_axis(xh, 3, SUBLANES)
     wh, _ = pad_axis(wh, 2, SUBLANES)
     if method == "basic_simd":
         out = K.conv2d_basic_simd(xh, wh, b, stride, padding, relu,

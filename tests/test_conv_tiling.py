@@ -5,10 +5,13 @@ non-multiple-of-8 channel counts, and frames large enough to force
 multiple oh-tiles (interpret mode) — including a 512×512×64 frame whose
 padded activations exceed the VMEM budget the seed kernel assumed.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels.conv2d import kernels as K
 from repro.kernels.conv2d.kernels import VMEM_BUDGET_BYTES, auto_oh_block
 from repro.kernels.conv2d.ops import conv2d as conv2d_pallas
 from repro.kernels.conv2d.ref import conv2d_ref
@@ -90,3 +93,72 @@ def test_auto_oh_block_monotone_and_bounded():
         prev = ohb
     # small frames fall back to a single whole-frame tile under a big budget
     assert auto_oh_block(13, 13, 15, 8, 3, 3, 1, 128) == 13
+
+
+def _nhwc_width_taps(x_nhwc, wmat, rows, px, kh, kw, sx, ow):
+    """The width-tap operand built the older way, through NHWC: pad the
+    rows and width, cut ``kw`` stride-``sx`` slices with C on the minor
+    axis, join them along C and pad to the lane width."""
+    lanes = K.LANES
+    xp = jnp.pad(x_nhwc, ((0, 0), rows, (px, px), (0, 0)))
+    n, hp, _, c = xp.shape
+    taps = [jax.lax.slice(xp, (0, 0, j, 0),
+                          (n, hp, j + (ow - 1) * sx + 1, c), (1, 1, sx, 1))
+            for j in range(kw)]
+    xu = jnp.pad(jnp.concatenate(taps, axis=-1),
+                 ((0, 0), (0, 0), (0, 0), (0, lanes - kw * c)))
+    oc = wmat.shape[1]
+    wu = jnp.pad(wmat.reshape(kh, kw * c, oc),
+                 ((0, 0), (0, lanes - kw * c), (0, 0)))
+    return xu, wu.reshape(kh * lanes, oc)
+
+
+#: the convs the packing rule takes (kw > 1, kw * Cp <= 128), cut down:
+#: name -> (x shape, w shape, conv2d keywords, top rows beyond the pad)
+_PACKED = {
+    # alexnet conv1 (11x11/4 + pool 3/2 + LRN): 4 pooled bands, bottom rows
+    "alexnet-conv1": ((2, 3, 67, 67), (16, 3, 11, 11),
+                      dict(stride=(4, 4), padding=(0, 0), relu=True,
+                           pool_kernel=(3, 3), pool_stride=(2, 2),
+                           lrn_n=5, lrn_alpha=1e-4, lrn_beta=0.75,
+                           lrn_k=1.0, oh_block=5), 0),
+    # cifar10 conv1 (5x5/1 pad 2 + pool 3/2) on the carry cell: the
+    # zero prepad rows above the frame
+    "cifar10-conv1": ((2, 3, 32, 32), (32, 3, 5, 5),
+                      dict(stride=(1, 1), padding=(2, 2), relu=True,
+                           pool_kernel=(3, 3), pool_stride=(2, 2),
+                           pool_carry=True, oh_block=8), 5),
+    # lenet5 conv1 (5x5/1, one channel + pool 2/2)
+    "lenet5-conv1": ((2, 1, 28, 28), (20, 1, 5, 5),
+                     dict(stride=(1, 1), padding=(0, 0), relu=False,
+                          pool_kernel=(2, 2), pool_stride=(2, 2)), 0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PACKED))
+def test_width_tap_operand_matches_the_nhwc_route(name, monkeypatch):
+    """The packed operand built from the NCHW frame is the one the NHWC
+    route builds, byte for byte, and so is the dispatch's output."""
+    xs, ws, kwargs, prepad = _PACKED[name]
+    x, w, b = (jax.random.normal(jax.random.PRNGKey(i), s, jnp.float32)
+               for i, s in enumerate((xs, ws, ws[:1])))
+    calls = []
+    packer = K._pack_width_taps
+
+    def spy(*args):
+        calls.append((args, packer(*args)))
+        return calls[-1][1]
+
+    # unjitted: each call traces the packer it finds
+    run = partial(conv2d_pallas.__wrapped__, x, w, b,
+                  method="advanced_simd_8", interpret=True, **kwargs)
+    monkeypatch.setattr(K, "_pack_width_taps", spy)
+    out = run()
+    monkeypatch.setattr(K, "_pack_width_taps", _nhwc_width_taps)
+    ref = run()
+    (args, (xu, wu)), = calls
+    assert args[2][0] == kwargs["padding"][0] + prepad
+    xr, wr = _nhwc_width_taps(*args)
+    assert xu.shape == xr.shape and xu.shape[-1] == K.LANES
+    assert bool(jnp.array_equal(xu, xr)) and bool(jnp.array_equal(wu, wr))
+    assert bool(jnp.array_equal(out, ref))

@@ -14,6 +14,8 @@ backend the wrappers see here is the CPU.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -94,6 +96,43 @@ def test_main_path_kernel_compiles_for_v5e(name, one_chip):
     lowered = jax.jit(fn).lower(*args)
     assert "tpu_custom_call" in lowered.as_text()  # a Mosaic kernel, not XLA
     lowered.compile()  # raises what the chip's compiler would refuse
+
+
+def _narrow_entry_slices(hlo_text):
+    """The entry computation's ``slice`` results whose minor dimension,
+    by their layout, holds 8 elements or fewer."""
+    entry = hlo_text[hlo_text.index("\nENTRY"):]
+    narrow = []
+    for m in re.finditer(r"= f32\[([\d,]+)\]\{([\d,]+)[:}][^\n]* slice\(",
+                         entry):
+        dims = [int(d) for d in m.group(1).split(",")]
+        minor = int(m.group(2).split(",")[0])
+        if dims[minor] <= 8:
+            narrow.append(m.group(0))
+    return narrow
+
+
+def test_conv1_operand_is_built_without_lane_padded_slices(one_chip):
+    """alexnet's conv1 dispatch builds its width-tap operand from the
+    NCHW frame: no slice with 3 or 8 channels on the lane axis, each
+    tiled to 128 lanes in HBM, and a temp near the 106 MB operand (the
+    NHWC route took 1.63 GB)."""
+    fn, shapes = CASES["alexnet-conv1+pool1+norm1"]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 256 * 2**20
+    assert _narrow_entry_slices(compiled.as_text()) == []
+
+
+def test_narrow_slices_are_found_in_hand_made_hlo():
+    hlo = ("HloModule m\n\nENTRY %main {\n"
+           "  %s.0 = f32[16,235,55,8]{3,2,1,0:T(8,128)} slice(%p), x\n"
+           "  %s.1 = f32[16,8,235,55]{1,3,2,0:T(8,128)} slice(%p), x\n"
+           "  %s.2 = f32[16,8,235,55]{3,2,1,0:T(8,128)} slice(%p), x\n"
+           "  %f = f32[16,235,55,128]{3,2,1,0} fusion(%s.2), x\n}\n")
+    found = _narrow_entry_slices(hlo)
+    assert len(found) == 2 and "s.2" not in "".join(found)
 
 
 def test_bucket_program_puts_each_kernel_under_its_plan_step(one_chip,

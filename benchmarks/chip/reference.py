@@ -1,11 +1,43 @@
 """Plain reference of a configuration's forward pass, in ``jax.numpy``.
 
-Built from the configuration's own layer list and imports nothing of the
-program: conv by ``lax.conv_general_dilated``, pooling by
-``lax.reduce_window`` (floor mode), LRN across channels, fc by ``dot``
-and a softmax, all in float32.  The weights are drawn from the seed by
-the scheme the configuration states (``weights``), not taken from the
-program.
+Built from the configuration's own layer graph and imports nothing of
+the program: conv by ``lax.conv_general_dilated``, pooling by
+``lax.reduce_window`` (floor mode), LRN across channels, fc by ``dot``,
+elementwise adds and a softmax, all in float32.  The weights are drawn
+from the seed by the scheme the configuration states (``weights``), not
+taken from the program.
+
+The configuration's ``layers`` are read in order; each is a dict with
+``kind`` (conv, pool, lrn, flatten, fc, add or softmax) and a unique
+``name`` (not ``"input"``), and the keys of its kind: conv ``out``,
+``kernel``, ``stride``, ``pad``, ``relu``; pool ``pool`` (max or avg),
+``kernel``, ``stride``, ``relu``; lrn ``size``, ``alpha``, ``beta``,
+``k``; fc ``out``, ``relu``.  The keys below are optional, and a
+configuration that states none of them is a chain:
+
+=========  =========  ================================================
+key        on         meaning (default)
+=========  =========  ================================================
+``inputs`` any layer  names of earlier layers, or ``"input"`` for the
+                      frame, that the layer reads (the layer before
+                      it; the frame for the first)
+``add``    (a kind)   the elementwise sum of its 2 or more ``inputs``,
+                      of equal shapes, then ReLU if ``relu`` (false)
+``groups`` conv       grouped convolution: weights ``[out, in/groups,
+                      kh, kw]``, ``feature_group_count=groups``; ``in``
+                      and ``out`` divisible by it (1)
+``pad``    pool       symmetric spatial padding ``[ph, pw]``: -inf for
+                      max, zeros counted in the divisor for avg
+                      (Caffe's rule); output ``(h+2ph-kh)//sh+1``
+                      (``[0, 0]``)
+``gain``   conv, fc   weights drawn as ``gain * N(0, 2/fan_in)``, with
+                      the draw's order and keys unchanged (1: no
+                      multiply)
+=========  =========  ================================================
+
+The last layer's output is the network's.  ``work.layer_shapes``
+resolves the graph and checks it; this module, ``work.py`` and
+``run.check_network`` read every key above.
 
 ``precision`` selects how every conv and fc contraction is computed:
 
@@ -18,12 +50,14 @@ program.
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, Iterator, List
+from typing import Dict, Iterator
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from benchmarks.chip import work
 
 #: the precision of every conv and fc contraction, by name
 PRECISIONS = {"highest": lax.Precision.HIGHEST, "high": lax.Precision.HIGH}
@@ -37,48 +71,26 @@ def weights_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(lo), hi)
 
 
-def param_shapes(config: dict) -> List[tuple]:
-    """``(name, kind, weight_shape)`` of each conv and fc layer, in
-    order (conv OIHW, fc ``[d_in, d_out]``)."""
-    shape = list(config["input"])
-    out = []
-    for layer in config["layers"]:
-        kind = layer["kind"]
-        if kind == "conv":
-            c, h, w = shape
-            kh, kw = layer["kernel"]
-            sh, sw = layer["stride"]
-            ph, pw = layer["pad"]
-            out.append((layer["name"], kind, (layer["out"], c, kh, kw)))
-            shape = [layer["out"], (h + 2 * ph - kh) // sh + 1,
-                     (w + 2 * pw - kw) // sw + 1]
-        elif kind == "pool":
-            c, h, w = shape
-            kh, kw = layer["kernel"]
-            sh, sw = layer["stride"]
-            shape = [c, (h - kh) // sh + 1, (w - kw) // sw + 1]
-        elif kind == "flatten":
-            shape = [int(np.prod(shape))]
-        elif kind == "fc":
-            out.append((layer["name"], kind, (shape[0], layer["out"])))
-            shape = [layer["out"]]
-    return out
-
-
 def make_params(config: dict, seed: int) -> Dict[str, Dict[str, jax.Array]]:
     """The configuration's weights from ``seed``: for each conv and fc
     layer in order, split the key and draw He-normal weights
-    (``N(0, 2/fan_in)``); biases are zero."""
+    (``N(0, 2/fan_in)``, times the layer's ``gain`` where it states
+    one); biases are zero."""
     key = weights_key(seed)
     params = {}
-    for name, kind, shape in param_shapes(config):
+    for row in work.layer_shapes(config):
+        if "weight_shape" not in row:
+            continue
+        shape = tuple(row["weight_shape"])
         key, sub = jax.random.split(key)
-        fan_in = int(np.prod(shape[1:])) if kind == "conv" else shape[0]
-        out = shape[0] if kind == "conv" else shape[1]
-        params[name] = {
-            "w": (2.0 / fan_in) ** 0.5 * jax.random.normal(sub, shape,
-                                                           jnp.float32),
-            "b": jnp.zeros((out,), jnp.float32)}
+        conv = row["kind"] == "conv"
+        fan_in = int(np.prod(shape[1:])) if conv else shape[0]
+        w = (2.0 / fan_in) ** 0.5 * jax.random.normal(sub, shape, jnp.float32)
+        if "gain" in row:
+            w = row["gain"] * w
+        params[row["name"]] = {
+            "w": w, "b": jnp.zeros((shape[0] if conv else shape[1],),
+                                   jnp.float32)}
     return params
 
 
@@ -93,18 +105,21 @@ def _conv_op(layer):
     return lambda a, b, **kw: lax.conv_general_dilated(
         a, b, tuple(layer["stride"]),
         [(layer["pad"][0],) * 2, (layer["pad"][1],) * 2],
-        dimension_numbers=("NCHW", "OIHW", "NCHW"), **kw)
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        feature_group_count=layer.get("groups", 1), **kw)
 
 
 def _pool(x, layer):
     kh, kw = layer["kernel"]
     sh, sw = layer["stride"]
+    ph, pw = layer.get("pad", [0, 0])
     dims, strides = (1, 1, kh, kw), (1, 1, sh, sw)
+    pads = ((0, 0), (0, 0), (ph, ph), (pw, pw))
     if layer["pool"] == "max":
-        y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, "VALID")
+        y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, pads)
     elif layer["pool"] == "avg":
         y = lax.reduce_window(x, 0.0, lax.add, dims, strides,
-                              "VALID") / (kh * kw)
+                              pads) / (kh * kw)
     else:
         raise ValueError(f"{layer['name']}: pool {layer['pool']!r}")
     return jnp.maximum(y, 0.0) if layer.get("relu") else y
@@ -120,8 +135,11 @@ def _lrn(x, layer):
 def forward(config: dict, params, x, precision: str = "highest"):
     """Class probabilities ``[N, num_classes]`` of frames ``x``
     ``[N, C, H, W]``."""
-    for layer in config["layers"]:
+    outs = {"input": x}
+    for layer in work.layer_shapes(config):
         kind = layer["kind"]
+        xs = [outs[n] for n in layer["inputs"]]
+        x = xs[0]
         if kind == "conv":
             p = params[layer["name"]]
             x = _contract(_conv_op(layer), x, p["w"], precision)
@@ -139,10 +157,14 @@ def forward(config: dict, params, x, precision: str = "highest"):
             x = _contract(jnp.dot, x, p["w"], precision) + p["b"]
             if layer["relu"]:
                 x = jnp.maximum(x, 0.0)
+        elif kind == "add":
+            for y in xs[1:]:
+                x = x + y
+            if layer.get("relu"):
+                x = jnp.maximum(x, 0.0)
         elif kind == "softmax":
             x = jax.nn.softmax(x, axis=-1)
-        else:
-            raise ValueError(f"{layer['name']}: unknown kind {kind!r}")
+        outs[layer["name"]] = x
     return x
 
 

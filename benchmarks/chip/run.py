@@ -37,9 +37,16 @@ ROOT = Path(__file__).resolve().parents[2]
 ENGINE_KW = {"use_pallas": True}
 
 
+def _plain(value):
+    return tuple(value) if isinstance(value, (list, tuple)) else value
+
+
 def check_network(config: dict, net) -> None:
     """Refuse a program whose network differs from the configuration:
-    the yardstick's work must be the program's work."""
+    the yardstick's work must be the program's work.  The optional keys
+    of ``reference.py``'s schema (``inputs``, conv ``groups``, pool
+    ``pad``, ``gain``) are compared where the configuration states
+    them; a program whose layer lacks the field differs."""
     want = []
     for layer in config["layers"]:
         kind = layer["kind"]
@@ -55,11 +62,18 @@ def check_network(config: dict, net) -> None:
             row.update(kernel=tuple(layer["kernel"]),
                        stride=tuple(layer["stride"]),
                        pool_kind=layer["pool"], relu=layer["relu"])
+            if "pad" in layer:
+                row["padding"] = tuple(layer["pad"])
         if kind == "lrn":
             row.update(lrn_n=layer["size"], lrn_alpha=layer["alpha"],
                        lrn_beta=layer["beta"], lrn_k=layer["k"])
+        if kind == "add":
+            row["relu"] = layer.get("relu", False)
+        for key in ("inputs", "groups", "gain"):
+            if key in layer:
+                row[key] = _plain(layer[key])
         want.append(row)
-    have = [{k: getattr(spec, k) for k in row}
+    have = [{k: _plain(getattr(spec, k, None)) for k in row}
             for spec, row in zip(net.layers, want)]
     if (list(net.input_shape) != list(config["input"])
             or net.num_classes != config["num_classes"]

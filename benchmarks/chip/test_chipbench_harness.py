@@ -10,12 +10,14 @@ import subprocess
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from benchmarks.chip import run, spec
 from benchmarks.chip import trace as tr
+from benchmarks.chip.test_chipbench_work import tiny_graph
 from repro.core.engine import CNNEngine
 
 ROOT = run.ROOT
@@ -199,6 +201,56 @@ def test_every_named_piece_exists():
             assert spec.cell_metrics(bench, cell["name"], per_layer)
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert callable(spec.metric_reader(m["name"]).read)
+
+
+def _stand_in(config: dict) -> SimpleNamespace:
+    """A network object as a program that read every key of the
+    configuration would build it."""
+    layers = []
+    for layer in config["layers"]:
+        spec_ = SimpleNamespace(kind=layer["kind"], name=layer["name"],
+                                relu=layer.get("relu", False))
+        if "out" in layer:
+            spec_.out_channels = layer["out"]
+        for key, field in (("kernel", "kernel"), ("stride", "stride"),
+                           ("pad", "padding"), ("inputs", "inputs")):
+            if key in layer:
+                setattr(spec_, field, tuple(layer[key]))
+        for key, field in (("pool", "pool_kind"), ("groups", "groups"),
+                           ("gain", "gain")):
+            if key in layer:
+                setattr(spec_, field, layer[key])
+        layers.append(spec_)
+    return SimpleNamespace(name=config["name"],
+                           input_shape=tuple(config["input"]),
+                           num_classes=config["num_classes"], layers=layers)
+
+
+def test_check_network_passes_a_program_that_reads_the_graph():
+    run.check_network(tiny_graph(), _stand_in(tiny_graph()))
+
+
+@pytest.mark.parametrize("fault", [
+    # the add reads the block's input instead of the projection
+    lambda ls: setattr(ls["b1_add"], "inputs", ("b1_conv3", "pool0")),
+    # the shortcut reads the wrong layer
+    lambda ls: setattr(ls["b1_proj"], "inputs", ("stem",)),
+    # the conv ignores groups: no such field, or a dense conv
+    lambda ls: delattr(ls["b1_conv2"], "groups"),
+    lambda ls: setattr(ls["b1_conv2"], "groups", 1),
+    # the pool ignores pad
+    lambda ls: setattr(ls["pool0"], "padding", (0, 0)),
+    # the draw ignores the gain
+    lambda ls: delattr(ls["b1_conv3"], "gain"),
+    # the add leaves out its ReLU
+    lambda ls: setattr(ls["b1_add"], "relu", False),
+], ids=["add-input", "shortcut-input", "groups-missing", "groups-1",
+        "pool-pad", "gain-missing", "add-relu"])
+def test_check_network_refuses_a_program_that_misreads_the_graph(fault):
+    net = _stand_in(tiny_graph())
+    fault({spec_.name: spec_ for spec_ in net.layers})
+    with pytest.raises(ValueError, match="differs"):
+        run.check_network(tiny_graph(), net)
 
 
 def test_benchmark_names_and_units():

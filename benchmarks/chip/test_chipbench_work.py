@@ -1,5 +1,7 @@
 """``work.py`` against hand-worked layers of the committed
-configurations."""
+configurations and of a small branching graph."""
+import json
+
 import pytest
 
 from benchmarks.chip import spec, work
@@ -55,3 +57,100 @@ def test_a_layer_outside_every_group_is_refused():
     cfg["kernels"]["fc"]["groups"] = [["fc1"]]
     with pytest.raises(ValueError, match="exactly one group"):
         work.group_work(cfg, 1)
+
+
+def tiny_graph() -> dict:
+    """A ResNet bottleneck at test size (``testdata/tiny_bottleneck.json``):
+    a stem conv, a padded max pool, 1x1 -> grouped 3x3 -> 1x1 with a gain,
+    a strided 1x1 projection shortcut, an add with ReLU, a global average
+    pool, an fc and a softmax."""
+    return json.loads((spec.HERE / "testdata" / "tiny_bottleneck.json")
+                      .read_text())
+
+
+#: the tiny graph by hand, per frame: (FLOPs, weights and biases)
+TINY_BY_HAND = {
+    "stem": (2 * 16 * 16 * 8 * 3 * 3 * 3, 8 * 3 * 9 + 8),
+    "pool0": (8 * 8 * 8 * 3 * 3, 0),              # (16 + 2 - 3)//2 + 1 = 8
+    "b1_conv1": (2 * 8 * 8 * 4 * 8, 4 * 8 + 4),
+    "b1_conv2": (2 * 4 * 4 * 4 * 2 * 3 * 3, 4 * 2 * 9 + 4),  # 2 in/group
+    "b1_conv3": (2 * 4 * 4 * 16 * 4, 16 * 4 + 16),
+    "b1_proj": (2 * 4 * 4 * 16 * 8, 16 * 8 + 16),
+    "b1_add": (1 * 16 * 4 * 4, 0),                # one add per element
+    "gap": (16 * 4 * 4, 0),
+    "flatten": (0, 0),
+    "fc": (2 * 16 * 10, 16 * 10 + 10),
+    "prob": (0, 0),
+}
+
+
+def test_graph_flops_and_weights_by_hand():
+    rows = {r["name"]: r for r in work.layer_shapes(tiny_graph())}
+    assert rows["b1_add"]["inputs"] == ["b1_conv3", "b1_proj"]
+    assert rows["b1_proj"]["inputs"] == ["pool0"]
+    assert rows["b1_conv1"]["inputs"] == ["pool0"]
+    assert rows["stem"]["inputs"] == ["input"]
+    assert rows["b1_conv2"]["weight_shape"] == [4, 2, 3, 3]
+    got = {n: (work.layer_flops(r), work.weight_elems(r))
+           for n, r in rows.items()}
+    assert got == TINY_BY_HAND
+    assert work.flops_per_frame(tiny_graph()) == 128_576 == sum(
+        f for f, _ in TINY_BY_HAND.values())
+
+
+def test_graph_group_bytes_by_hand():
+    batch, b = 3, 4  # float32 activations and weights
+    groups = work.group_work(tiny_graph(), batch)
+    got = {g["name"]: (g["flops"], g["bytes"])
+           for fam in groups.values() for g in fam}
+    flops = {n: f * batch for n, (f, _) in TINY_BY_HAND.items()}
+    frame, pool0, block, gap = 3 * 16 * 16, 8 * 8 * 8, 16 * 4 * 4, 16
+    assert got == {
+        "stem+pool0": (flops["stem"] + flops["pool0"],
+                       b * batch * (frame + pool0) + b * 224),
+        # reads the shortcut from outside: pool0 and b1_proj in, the sum out
+        "b1_conv1+b1_conv2+b1_conv3+b1_add": (
+            flops["b1_conv1"] + flops["b1_conv2"] + flops["b1_conv3"]
+            + flops["b1_add"],
+            b * batch * (pool0 + block + block) + b * (36 + 76 + 80)),
+        "b1_proj": (flops["b1_proj"], b * batch * (pool0 + block) + b * 144),
+        "gap": (flops["gap"], b * batch * (block + gap)),
+        "fc": (flops["fc"], b * batch * (gap + 10) + b * 170),
+    }
+
+
+def test_a_group_reads_a_shared_input_once_and_writes_what_leaves_it():
+    cfg = tiny_graph()
+    cfg["kernels"]["conv"]["groups"] = [
+        ["stem", "pool0"],
+        ["b1_conv1", "b1_conv2", "b1_conv3", "b1_proj", "b1_add"], ["gap"]]
+    block = work.group_work(cfg, 1)["conv"][1]
+    # both branches read pool0: once in, the sum out
+    assert block["bytes"] == 4 * (8 * 8 * 8 + 16 * 4 * 4) + 4 * (
+        36 + 76 + 80 + 144)
+    # a group that ends the network writes its output
+    cfg["layers"] = cfg["layers"][:-1]  # no softmax: fc's is the output
+    assert work.group_work(cfg, 1)["fc"][0]["bytes"] == \
+        4 * (16 + 10) + 4 * 170
+
+
+def _broken(change):
+    cfg = tiny_graph()
+    change({layer["name"]: layer for layer in cfg["layers"]})
+    return cfg
+
+
+@pytest.mark.parametrize("change,match", [
+    (lambda ls: ls["b1_add"].update(inputs=["b1_conv3", "b1_conv1"]),
+     "one shape"),
+    (lambda ls: ls["b1_add"].update(inputs=["b1_conv3"]), "2 or more"),
+    (lambda ls: ls["b1_proj"].update(inputs=["b1_add"]), "not earlier"),
+    (lambda ls: ls["b1_conv1"].update(inputs=["pool0", "stem"]),
+     "reads one input"),
+    (lambda ls: ls["b1_conv2"].update(groups=3), "groups"),
+    (lambda ls: ls["b1_proj"].update(name="stem"), "used twice"),
+    (lambda ls: ls["gap"].update(name="input"), "reserved"),
+])
+def test_a_malformed_graph_is_refused(change, match):
+    with pytest.raises(ValueError, match=match):
+        work.layer_shapes(_broken(change))

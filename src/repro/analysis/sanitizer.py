@@ -106,6 +106,18 @@ def _a_auto_oh(oh, ow, wp, c, kh, kw, sy, oc_block,
     return 1
 
 
+def _a_basic_ocb(oc, ow, wp, c, kh, kw, sy):
+    """Phase-A re-derivation of an un-fused basic_simd cell's oc tile:
+    full width unless its one-row floor cell busts the (re-stated)
+    budget, then halved while the half is a whole number of 128-lane
+    tiles."""
+    ocb = oc
+    while (_a_conv_cell(1, ow, wp, c, kh, kw, sy, ocb, im2col=False)
+           > _A_VMEM_BUDGET and ocb % 256 == 0):
+        ocb //= 2
+    return ocb
+
+
 def _a_resolve_oh(oh, ow, wp, c, kh, kw, sy, oc_block, oh_block,
                   im2col=True):
     if oh_block is None:
@@ -2004,6 +2016,7 @@ _INTERCEPTS = {
         "band_intervals": _a_intervals,
         "auto_oh_block": _a_auto_oh,
         "resolve_oh_block": _a_resolve_oh,
+        "resolve_basic_ocb": _a_basic_ocb,
         "auto_ph_block": _a_auto_ph,
         "resolve_ph_block": _a_resolve_ph,
         "resolve_lrn_ocb": _a_resolve_lrn_ocb,
@@ -2134,14 +2147,17 @@ def sanitize_conv2d(x_shape, w_shape, *, stride=(1, 1), padding=(0, 0),
                     relu=False, im2col=True, oc_block=128, oh_block=None,
                     pool_kernel=None, pool_stride=None, pool_kind="max",
                     pool_relu=False, lrn=None, pool_carry=None,
-                    lrn_oc_block=None, sources=None, label=None):
+                    lrn_oc_block=None, residual=False, sources=None,
+                    label=None):
     """Prove one (possibly pool/LRN-fused) SIMD conv dispatch.
 
     ``x_shape`` NHWC, ``w_shape`` HWIO — pass the PADDED operand shapes
     the engine actually dispatches.  ``pool_carry``/``lrn_oc_block``
     mirror the dispatch knobs (None = the resolvers' auto rule, re-
-    derived here by Phase A).  Returns ``(findings, geom)`` where
-    ``geom`` is the Phase-A band geometry for the K105 cross-check —
+    derived here by Phase A); ``residual`` adds the residual join's
+    shortcut operand, an NHWC array of the output's shape.  Returns
+    ``(findings, geom)`` where ``geom`` is the Phase-A band geometry for
+    the K105 cross-check —
     ``carry`` is the input rows the sliding-window accumulator carries
     between bands (0 for classic cells) and ``steps`` the physical grid
     steps on the band axis (``n_tiles + 1`` with carry: step 0 is the
@@ -2162,7 +2178,9 @@ def sanitize_conv2d(x_shape, w_shape, *, stride=(1, 1), padding=(0, 0),
     else:
         pool = None
     if not im2col:
-        ocb, oc_halo = oc, 0
+        ocb = oc if pool is not None else _a_basic_ocb(oc, ow, wp, c, kh,
+                                                       kw, sy)
+        oc_halo = 0
     elif lrn is not None and pool is not None:
         ocb, oc_halo = _a_resolve_lrn_ocb(oc, oc_block, lrn, lrn_oc_block,
                                           ow, wp, c, kh, kw, sy, pool)
@@ -2204,6 +2222,8 @@ def sanitize_conv2d(x_shape, w_shape, *, stride=(1, 1), padding=(0, 0),
                 "band": _a_band(blk, kh, sy), "row_step": blk * sy,
                 "in_base": 0, "carry": 0, "steps": _ceil_div(oh, blk)}
         expected = (n, oh, ow, oc)
+    if residual:
+        kwargs["residual"] = AArray(expected, "io")
     x = AArray(x_shape, "io")
     w = AArray(w_shape, "io")
     b = AArray((oc,), "io")

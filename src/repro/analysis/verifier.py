@@ -37,10 +37,9 @@ from repro.analysis.findings import (  # noqa: F401  (re-exported for
     PlanVerificationError,
 )
 from repro.core.fusion import (
-    ADVANCED_OC_BLOCK,
-    IM2COL_METHODS,
     _conv_out_hw,
     _pool_out_hw,
+    conv_band_params,
     group_band_params,
 )
 from repro.core.methods import Method
@@ -155,38 +154,16 @@ def step_band_params(plan: ExecutionPlan,
     from repro.kernels.conv2d import kernels as K
     from repro.kernels.conv2d.ops import SUBLANES
 
-    if step.kind in ("fused", "chain"):
+    if step.kind in ("fused", "chain", "residual"):
         kw = step.kwargs or {}
         return (group_band_params(step.group, step.method, step.in_shape,
                                   step.oh_block,
                                   pool_carry=kw.get("pool_carry"),
                                   lrn_oc_block=kw.get("lrn_oc_block")),
-                True)
+                step.kind != "residual")
     if step.kind == "conv" and step.method in _BANDED_METHODS:
-        spec = step.spec
-        c, h, w = step.in_shape
-        kh, kw = spec.kernel
-        sy = spec.stride[0]
-        oh, ow = _conv_out_hw(h, w, spec)
-        cp = -(-c // SUBLANES) * SUBLANES
-        wp = w + 2 * spec.padding[1]
-        im2col = step.method in IM2COL_METHODS
-        ocb = (min(ADVANCED_OC_BLOCK, spec.out_channels)
-               if im2col else spec.out_channels)
-        blk = K.resolve_oh_block(oh, ow, wp, cp, kh, kw, sy, ocb,
-                                 step.oh_block, im2col=im2col)
-        return ({
-            "kind": "conv", "blk": blk, "n_tiles": -(-oh // blk),
-            "total": oh, "band": K._band_rows(blk, kh, sy),
-            "row_step": blk * sy, "in_base": 0, "stride_eff": sy,
-            "carry": 0, "steps": -(-oh // blk),
-            "window_eff": kh, "padded_h": h + 2 * spec.padding[0],
-            "cell_bytes": K.conv_cell_bytes(blk, ow, wp, cp, kh, kw, sy,
-                                            ocb, im2col=im2col),
-            "floor_bytes": K.conv_cell_bytes(1, ow, wp, cp, kh, kw, sy,
-                                             ocb, im2col=im2col),
-            "budget": K.VMEM_BUDGET_BYTES, "out_hw": [oh, ow],
-        }, False)
+        return (conv_band_params(step.spec, step.method, step.in_shape,
+                                 step.oh_block), False)
     if step.kind == "pool" and plan.use_pallas:
         from repro.kernels.pool2d.kernels import auto_oh_block_pool
 
@@ -195,18 +172,20 @@ def step_band_params(plan: ExecutionPlan,
         kh, _kw = spec.kernel
         sy = spec.stride[0]
         oh, ow = _pool_out_hw(h, w, spec)
+        # the kernel pools the padded frame with VALID windows
+        hp, wp = h + 2 * spec.padding[0], w + 2 * spec.padding[1]
         cp = -(-c // SUBLANES) * SUBLANES
-        blk = auto_oh_block_pool(oh, ow, w, cp, kh, sy)
+        blk = auto_oh_block_pool(oh, ow, wp, cp, kh, sy)
         blk = max(1, min(blk, oh))
         return ({
             "kind": "pool", "blk": blk, "n_tiles": -(-oh // blk),
             "total": oh, "band": K._band_rows(blk, kh, sy),
             "row_step": blk * sy, "in_base": 0, "stride_eff": sy,
             "carry": 0, "steps": -(-oh // blk),
-            "window_eff": kh, "padded_h": h,  # VALID pooling: no pad
-            "cell_bytes": K.conv_cell_bytes(blk, ow, w, cp, kh, _kw, sy, 0,
+            "window_eff": kh, "padded_h": hp,
+            "cell_bytes": K.conv_cell_bytes(blk, ow, wp, cp, kh, _kw, sy, 0,
                                             im2col=False),
-            "floor_bytes": K.conv_cell_bytes(1, ow, w, cp, kh, _kw, sy, 0,
+            "floor_bytes": K.conv_cell_bytes(1, ow, wp, cp, kh, _kw, sy, 0,
                                              im2col=False),
             "budget": K.VMEM_BUDGET_BYTES, "out_hw": [oh, ow],
         }, False)
@@ -219,7 +198,7 @@ def _derived_out_shape(step: PlanStep) -> Optional[Tuple[int, ...]]:
         _, h, w = cur
         h, w = _conv_out_hw(h, w, step.spec)
         return (step.spec.out_channels, h, w)
-    if step.kind in ("fused", "chain"):
+    if step.kind in ("fused", "chain", "residual"):
         _, h, w = cur
         for cv in step.group.convs:
             h, w = _conv_out_hw(h, w, cv)
@@ -234,13 +213,16 @@ def _derived_out_shape(step: PlanStep) -> Optional[Tuple[int, ...]]:
         return ((int(cur[0] * cur[1] * cur[2]),) if len(cur) == 3 else cur)
     if step.kind == "fc":
         return (step.spec.out_channels,)
-    if step.kind in ("lrn", "relu", "softmax"):
+    if step.kind in ("lrn", "relu", "softmax", "add"):
         return cur
     return None
 
 
 def _shape_findings(step: PlanStep, label: str, cur: Tuple[int, ...],
-                    shapes: dict) -> List[Finding]:
+                    added: dict, shapes: dict) -> List[Finding]:
+    """``cur`` is the shape of the tensor the step reads first, and
+    ``added`` the shapes of the tensors an add or residual step sums
+    into its output, by name."""
     findings: List[Finding] = []
     if tuple(step.in_shape) != tuple(cur):
         findings.append(Finding(
@@ -259,6 +241,12 @@ def _shape_findings(step: PlanStep, label: str, cur: Tuple[int, ...],
                 "error", label, "V101",
                 f"step output shape {tuple(step.out_shape)} != derived "
                 f"{want}"))
+    for name, shape in added.items():
+        if tuple(shape) != tuple(step.out_shape):
+            findings.append(Finding(
+                "error", label, "V101",
+                f"adds {name} of shape {tuple(shape)} to an output of "
+                f"shape {tuple(step.out_shape)}"))
     # parameter geometry vs infer_param_shapes
     if step.kind == "conv":
         kh, kw = step.spec.kernel
@@ -268,7 +256,7 @@ def _shape_findings(step: PlanStep, label: str, cur: Tuple[int, ...],
                 "error", label, "V103",
                 f"conv {step.spec.name} weight {shapes.get(step.spec.name)} "
                 f"!= step-derived {want_w}"))
-    elif step.kind in ("fused", "chain"):
+    elif step.kind in ("fused", "chain", "residual"):
         c = step.in_shape[0]
         for cv in step.group.convs:
             kh, kw = cv.kernel
@@ -328,21 +316,33 @@ def verify_plan(plan: ExecutionPlan, net: Optional[NetworkDef] = None,
     ``input_shape`` default to the plan's own — pass them to check a
     plan against an independently-trusted definition (deploy does)."""
     net = net if net is not None else plan.net
-    cur: Tuple[int, ...] = tuple(input_shape if input_shape is not None
-                                 else net.input_shape)
+    # each step reads tensors by name: the frame, or an earlier step's
+    # output (V102 where none wrote it)
+    produced = {"input": tuple(input_shape if input_shape is not None
+                               else net.input_shape)}
     shapes = infer_param_shapes(net)
     findings: List[Finding] = []
     for idx, step in enumerate(plan.steps):
         label = f"step{idx}:{step.label}"
-        findings += _shape_findings(step, label, cur, shapes)
+        unknown = [n for n in step.inputs if n not in produced]
+        if unknown or not step.inputs:
+            findings.append(Finding(
+                "error", label, "V102",
+                f"step reads {unknown or 'nothing'}: no earlier step "
+                f"writes it"))
+        cur = produced.get(step.inputs[0] if step.inputs else None,
+                           tuple(step.in_shape))
+        added = {n: produced[n] for n in step.inputs[1:] if n in produced}
+        findings += _shape_findings(step, label, cur, added, shapes)
         geo, equalized = step_band_params(plan, step)
         if geo is not None:
             findings += check_band_coverage(geo, label, equalized=equalized)
             findings += _budget_findings(geo, label, plan,
                                          step.oh_block is not None)
-        cur = tuple(step.out_shape)
+        produced[step.output] = tuple(step.out_shape)
     # headless nets (tests, feature extractors) end wherever they end; a
     # classifier tail must land exactly on the class distribution
+    cur = tuple(plan.steps[-1].out_shape) if plan.steps else ()
     if (plan.steps and plan.steps[-1].kind in ("fc", "softmax")
             and tuple(cur) != (net.num_classes,)):
         findings.append(Finding(

@@ -376,12 +376,14 @@ def step_resources(plan: ExecutionPlan, step: PlanStep,
     from repro.analysis.verifier import step_band_params
 
     label = step.label
-    if step.kind in ("fused", "chain"):
+    if step.kind in ("fused", "chain", "residual"):
         geo, _ = step_band_params(plan, step)
-        return replace(
-            _group_resources(step.group, step.method, step.in_shape, batch,
-                             plan.use_pallas, step.oh_block, geo=geo),
-            label=label)
+        sc = _group_resources(step.group, step.method, step.in_shape, batch,
+                              plan.use_pallas, step.oh_block, geo=geo)
+        if step.kind == "residual":  # the shortcut, read once
+            sc = replace(sc, kind="residual", hbm_bytes=sc.hbm_bytes
+                         + batch * _act_bytes(step.out_shape))
+        return replace(sc, label=label)
     if step.kind == "conv":
         geo, _ = step_band_params(plan, step)
         spec = step.spec
@@ -428,6 +430,12 @@ def step_resources(plan: ExecutionPlan, step: PlanStep,
             label=label, kind="lrn", key="other",
             flops=batch * n_elems * (step.spec.lrn_n + 4),
             hbm_bytes=batch * 2 * _act_bytes(step.in_shape),
+            vmem_bytes=0, dispatches=1)
+    if step.kind == "add":  # bytes only: every input read, one written
+        return StepCost(
+            label=label, kind="add", key="other", flops=0.0,
+            hbm_bytes=batch * (len(step.inputs) + 1)
+            * _act_bytes(step.out_shape),
             vmem_bytes=0, dispatches=1)
     if step.kind in ("relu", "softmax"):
         n_elems = 1

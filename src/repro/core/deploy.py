@@ -158,7 +158,8 @@ def load_model(path) -> Tuple[NetworkDef, dict, dict]:
         layers=tuple(
             LayerSpec(**{**l, "kernel": tuple(l["kernel"]),
                          "stride": tuple(l["stride"]),
-                         "padding": tuple(l["padding"])})
+                         "padding": tuple(l["padding"]),
+                         "inputs": tuple(l.get("inputs", ()))})
             for l in nd["layers"]
         ),
     )

@@ -45,7 +45,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.analysis.findings import Finding, PlanVerificationError
 from repro.core.methods import Method, conv2d
-from repro.core.netdefs import NetworkDef
+from repro.core.netdefs import NetworkDef, layer_inputs
 from repro.core.plan import (  # noqa: F401  (_pool/_lrn re-exported: the
     ExecutionPlan,             # executors moved to the plan IR but their
     _lrn,                      # home here predates it)
@@ -252,26 +252,23 @@ class CNNEngine:
 
     # -- parameters -----------------------------------------------------------
     def init(self, key) -> Dict[str, Dict[str, jnp.ndarray]]:
+        """Seeded weights: for each conv and fc layer in order, split the
+        key and draw He-normal weights (``N(0, 2/fan_in)``), times the
+        layer's ``gain`` where it is not 1; zero biases."""
         params = {}
         for spec in self.net.layers:
-            if spec.kind == "conv":
-                oc, ic, kh, kw = self._shapes[spec.name]
-                key, k1 = jax.random.split(key)
-                std = (2.0 / (ic * kh * kw)) ** 0.5
-                params[spec.name] = {
-                    "w": std * jax.random.normal(k1, (oc, ic, kh, kw),
-                                                 jnp.float32),
-                    "b": jnp.zeros((oc,), jnp.float32),
-                }
-            elif spec.kind == "fc":
-                d_in, d_out = self._shapes[spec.name]
-                key, k1 = jax.random.split(key)
-                std = (2.0 / d_in) ** 0.5
-                params[spec.name] = {
-                    "w": std * jax.random.normal(k1, (d_in, d_out),
-                                                 jnp.float32),
-                    "b": jnp.zeros((d_out,), jnp.float32),
-                }
+            if spec.kind not in ("conv", "fc"):
+                continue
+            shape = self._shapes[spec.name]
+            fan_in = (int(np.prod(shape[1:])) if spec.kind == "conv"
+                      else shape[0])
+            key, k1 = jax.random.split(key)
+            w = (2.0 / fan_in) ** 0.5 * jax.random.normal(k1, shape,
+                                                         jnp.float32)
+            if spec.gain != 1.0:
+                w = spec.gain * w
+            out = shape[0] if spec.kind == "conv" else shape[1]
+            params[spec.name] = {"w": w, "b": jnp.zeros((out,), jnp.float32)}
         return params
 
     # -- forward ----------------------------------------------------------------
@@ -454,17 +451,17 @@ class CNNEngine:
         """The conv layer with the most MACs (paper Table 4 target) and its
         input activation."""
         best, best_macs, best_in = None, -1, None
-        acts: dict = {}
+        acts: dict = {"input": x}
         self.forward(params, x, collect=acts)
-        cur = x
+        inputs = layer_inputs(self.net)
         for spec in self.net.layers:
             if spec.kind == "conv":
                 oc, ic, kh, kw = self._shapes[spec.name]
                 out = acts[spec.name]
                 macs = int(np.prod(out.shape)) * ic * kh * kw
                 if macs > best_macs:
-                    best, best_macs, best_in = spec, macs, cur
-            cur = acts[spec.name]
+                    best, best_macs = spec, macs
+                    best_in = acts[inputs[spec.name][0]]
         return best.name, best_in
 
     def conv_layer_fn(self, name: str, method: Method,

@@ -27,6 +27,8 @@ Correctness fallbacks — layers stay on the per-layer ladder when:
 * the pool kind is not max/avg,
 * the pool window is larger than the conv output (shape-checked by
   propagating spatial dims through the net),
+* a conv after the first has a stride (a chain's later stage reads a
+  VMEM value, which Mosaic cannot slice with a stride),
 * a conv, pool, or lrn layer is named in ``no_fuse`` (per-layer opt-out,
   mirroring ``per_layer_methods``; an opted-out LRN only drops the LRN
   from the group, an opted-out conv breaks the chain at that conv),
@@ -43,15 +45,31 @@ Correctness fallbacks — layers stay on the per-layer ladder when:
   chains) exceeds the budget, the planner first drops the LRN tail,
   then falls back to successively SHORTER chains (the detached tail
   layers re-enter the scan and may group among themselves) before
-  declining fusion outright.
+  declining fusion outright,
+* the graph branches: a layer joins a group only if it reads the
+  group's last output and is that tensor's one reader, and a padded
+  pool never joins one.
+
+Residual joins (Pallas path, ``residual=True``): an ``add`` runs as the
+epilogue of a conv that feeds it — ``relu?(conv + b + shortcut)`` in
+one dispatch — when the add is the conv's one reader, the conv has no
+ReLU of its own and runs on a SIMD method.  Such a conv runs alone: it
+is decided before chains are, so a chain stops before it, and the group
+takes the add's place in the plan (the shortcut may be computed after
+the conv in network order).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Iterable, List, Optional, Tuple, Union
+from typing import Callable, Dict, Iterable, List, Optional, Tuple, Union
 
 from repro.core.methods import Method
-from repro.core.netdefs import LayerSpec, NetworkDef
+from repro.core.netdefs import (
+    LayerSpec,
+    NetworkDef,
+    layer_inputs,
+    layer_readers,
+)
 
 #: methods whose kernels support the fused pooling epilogue
 FUSABLE_METHODS = frozenset({
@@ -88,6 +106,10 @@ class FusedLayerSpec:
     #: full-width resident weights bust the budget; incompatible with a
     #: fused LRN tail (the kernel raises).
     oc_block_final: Optional[int] = None
+    #: residual join: the ``add`` layer this single conv's epilogue runs
+    #: and the tensor it adds (no pool or LRN then)
+    add: Optional[LayerSpec] = None
+    shortcut: Optional[str] = None
 
     kind = "fused"  # sentinel so plan items can be dispatched on .kind
 
@@ -117,8 +139,34 @@ def _conv_out_hw(h: int, w: int, spec: LayerSpec) -> Tuple[int, int]:
 
 def _pool_out_hw(h: int, w: int, spec: LayerSpec) -> Tuple[int, int]:
     kh, kw = spec.kernel
-    return ((h - kh) // spec.stride[0] + 1,
-            (w - kw) // spec.stride[1] + 1)
+    ph, pw = spec.padding
+    return ((h + 2 * ph - kh) // spec.stride[0] + 1,
+            (w + 2 * pw - kw) // spec.stride[1] + 1)
+
+
+def layer_shapes(net: NetworkDef) -> Dict[str, Tuple[int, ...]]:
+    """The output shape of every layer of ``net`` by name, and the
+    frame's as ``"input"``: ``(C, H, W)`` while spatial, ``(D,)`` once
+    flattened.  An fc reads all of a spatial activation; an add, lrn,
+    relu or softmax keeps its first input's shape."""
+    shapes: Dict[str, Tuple[int, ...]] = {"input": tuple(net.input_shape)}
+    for spec, ins in zip(net.layers, layer_inputs(net).values()):
+        missing = [n for n in ins if n not in shapes]
+        if missing:
+            raise ValueError(f"{spec.name} reads {missing}: not the frame "
+                             "or an earlier layer")
+        cur = shapes[ins[0]]
+        if spec.kind == "conv":
+            cur = (spec.out_channels, *_conv_out_hw(cur[1], cur[2], spec))
+        elif spec.kind == "pool":
+            cur = (cur[0], *_pool_out_hw(cur[1], cur[2], spec))
+        elif spec.kind in ("flatten", "fc"):
+            flat = 1
+            for d in cur:
+                flat *= int(d)
+            cur = (flat if spec.kind == "flatten" else spec.out_channels,)
+        shapes[spec.name] = cur
+    return shapes
 
 
 def fused_working_set(conv: LayerSpec, pool: LayerSpec, method: Method,
@@ -216,9 +264,11 @@ def plan_fusion(net: NetworkDef, *,
                 fuse_relu: bool = True,
                 vmem_budget: Optional[int] = None,
                 vmem_check: bool = True,
-                cost_gate: Optional[CostGate] = None) -> List[PlanItem]:
+                cost_gate: Optional[CostGate] = None,
+                residual: bool = False) -> List[PlanItem]:
     """Greedy left-to-right grouping of conv-chain[+relu][+pool][+lrn]
-    runs.
+    runs, after the residual joins (``residual=True``; the engine passes
+    its ``use_pallas``).
 
     ``method_for`` maps a conv layer name to its execution ``Method`` (the
     engine passes its per-layer resolution; ``None`` assumes the widest
@@ -238,42 +288,80 @@ def plan_fusion(net: NetworkDef, *,
     declined candidate walks the same fallback ladder: drop the LRN
     tail, then trailing convs, then decline outright.
     Returns the layer sequence with each fused run replaced by one
-    ``FusedLayerSpec``; ungrouped layers pass through unchanged.
+    ``FusedLayerSpec`` (a residual join in its add's place); ungrouped
+    layers pass through unchanged.
     """
     no_fuse = frozenset(no_fuse)
     layers = list(net.layers)
+    inputs = layer_inputs(net)
+    readers = layer_readers(net)
+    shapes = layer_shapes(net)
+
+    def feeds(src: str, layer: LayerSpec) -> bool:
+        """``layer`` reads ``src`` alone and is its one reader."""
+        return (inputs[layer.name] == (src,)
+                and readers.get(src) == [layer.name])
+
+    # a conv that can run its add's epilogue joins no chain, on either
+    # path; the XLA path then runs the conv and the add as two steps
+    joins = _residual_joins(layers, inputs, readers, method_for, no_fuse)
+    held = {cv.name for cv, _ in joins.values()}
     plan: List[PlanItem] = []
-    c, h, w = net.input_shape
     i = 0
     while i < len(layers):
         spec = layers[i]
-        if spec.kind == "conv":
+        if residual and spec.name in joins:
+            cv, shortcut = joins[spec.name]
+            plan.append(FusedLayerSpec(
+                convs=(cv,), relus=(False,), pool=None, pool_relu=False,
+                names=(cv.name, spec.name), add=spec, shortcut=shortcut))
+        elif spec.kind == "conv" and spec.name not in held:
+            cin, h, w = shapes[inputs[spec.name][0]]
             group = _try_group(layers, i, method_for, no_fuse, fuse_relu,
-                               c, h, w, vmem_budget, vmem_check, cost_gate)
+                               cin, h, w, vmem_budget, vmem_check, cost_gate,
+                               feeds=feeds, held=held)
             if group is not None:
                 plan.append(group)
-                for cv in group.convs:
-                    h, w = _conv_out_hw(h, w, cv)
-                c = group.convs[-1].out_channels
-                if group.pool is not None:
-                    h, w = _pool_out_hw(h, w, group.pool)
                 i += len(group.names)
                 continue
-            h, w = _conv_out_hw(h, w, spec)
-            c = spec.out_channels
-        elif spec.kind == "pool":
-            h, w = _pool_out_hw(h, w, spec)
-        plan.append(spec)
+            plan.append(spec)
+        elif not (residual and spec.name in held):
+            plan.append(spec)
         i += 1
     return plan
 
 
+def _residual_joins(layers, inputs, readers, method_for, no_fuse):
+    """``{add name: (conv, shortcut name)}``: each two-input add whose
+    epilogue a conv feeding it can run — the conv's one reader is the
+    add, it has no ReLU of its own and runs on a SIMD method."""
+    by_name = {spec.name: spec for spec in layers}
+    joins = {}
+    for add in layers:
+        ins = inputs[add.name]
+        if add.kind != "add" or add.name in no_fuse or len(ins) != 2:
+            continue
+        for src, shortcut in (ins, ins[::-1]):
+            cv = by_name.get(src)
+            if (cv is not None and cv.kind == "conv" and not cv.relu
+                    and cv.name not in no_fuse
+                    and readers.get(src) == [add.name]
+                    and (method_for is None
+                         or method_for(src) in FUSABLE_METHODS)):
+                joins[add.name] = (cv, shortcut)
+                break
+    return joins
+
+
 def _try_group(layers, i, method_for, no_fuse, fuse_relu, cin, h_in, w_in,
                vmem_budget, vmem_check=True,
-               cost_gate: Optional[CostGate] = None,
+               cost_gate: Optional[CostGate] = None, *, feeds, held,
                ) -> Optional[FusedLayerSpec]:
     """A FusedLayerSpec for the run starting at conv ``layers[i]``, or
-    None when any eligibility check fails (the per-layer fallback)."""
+    None when any eligibility check fails (the per-layer fallback).
+    ``feeds(src, layer)`` says whether ``layer`` is the one reader of
+    ``src`` and reads nothing else; ``held`` names the convs that run a
+    residual join, which no chain absorbs."""
     first = layers[i]
     if first.name in no_fuse:
         return None
@@ -288,7 +376,8 @@ def _try_group(layers, i, method_for, no_fuse, fuse_relu, cin, h_in, w_in,
     j = i + 1
     blocked_by_relu = False  # an un-foldable standalone ReLU ends the run
     while True:
-        if j < len(layers) and layers[j].kind == "relu":
+        if (j < len(layers) and layers[j].kind == "relu"
+                and feeds(conv_names[-1][-1], layers[j])):
             if not fuse_relu:
                 blocked_by_relu = True
                 break
@@ -296,7 +385,11 @@ def _try_group(layers, i, method_for, no_fuse, fuse_relu, cin, h_in, w_in,
             conv_names[-1].append(layers[j].name)
             j += 1
         nxt = layers[j] if j < len(layers) else None
+        # a later stage reads a VMEM value, which Mosaic cannot slice
+        # with a stride: strided convs only lead a chain
         if (nxt is None or nxt.kind != "conv" or nxt.name in no_fuse
+                or nxt.name in held or nxt.stride != (1, 1)
+                or not feeds(conv_names[-1][-1], nxt)
                 or (method_for is not None
                     and method_for(nxt.name) != method)):
             break
@@ -313,10 +406,12 @@ def _try_group(layers, i, method_for, no_fuse, fuse_relu, cin, h_in, w_in,
     pool_relu = False
     pool_names: List[str] = []
     lrn = None
-    if not blocked_by_relu and j < len(layers) and layers[j].kind == "pool":
+    if (not blocked_by_relu and j < len(layers) and layers[j].kind == "pool"
+            and feeds(conv_names[-1][-1], layers[j])):
         p = layers[j]
         pkh, pkw = p.kernel
         if (p.name not in no_fuse and p.pool_kind in SUPPORTED_POOL_KINDS
+                and p.padding == (0, 0)
                 and pkh >= 1 and pkw >= 1
                 and p.stride[0] >= 1 and p.stride[1] >= 1
                 and pkh <= h and pkw <= w):
@@ -324,12 +419,14 @@ def _try_group(layers, i, method_for, no_fuse, fuse_relu, cin, h_in, w_in,
             pool_relu = p.relu
             pool_names = [p.name]
             k = j + 1
-            if fuse_relu and k < len(layers) and layers[k].kind == "relu":
+            if (fuse_relu and k < len(layers) and layers[k].kind == "relu"
+                    and feeds(p.name, layers[k])):
                 pool_relu = True
                 pool_names.append(layers[k].name)
                 k += 1
             if (k < len(layers) and layers[k].kind == "lrn"
-                    and layers[k].name not in no_fuse):
+                    and layers[k].name not in no_fuse
+                    and feeds(pool_names[-1], layers[k])):
                 lrn = layers[k]
     # -- admission check with shorter-chain fallback -----------------------
     # Raw VMEM working-set check (Pallas path only): the fused kernel
@@ -467,6 +564,8 @@ def group_band_params(group: FusedLayerSpec, method: Method,
     from repro.kernels.conv2d import kernels as K
     from repro.kernels.conv2d.ops import SUBLANES
 
+    if group.add is not None:  # a residual join banks like its conv
+        return conv_band_params(group.conv, method, in_shape, oh_block)
     c, h, w = in_shape
     im2col = method in IM2COL_METHODS
     cp = -(-c // SUBLANES) * SUBLANES
@@ -555,6 +654,40 @@ def group_band_params(group: FusedLayerSpec, method: Method,
     return geo
 
 
+def conv_band_params(spec: LayerSpec, method: Method,
+                     in_shape: Tuple[int, int, int],
+                     oh_block: Optional[int]) -> dict:
+    """The band geometry of one conv's own Pallas cell, as
+    ``group_band_params`` gives a group's: the output-row band
+    ``resolve_oh_block`` picks, with no pool or LRN epilogue."""
+    from repro.kernels.conv2d import kernels as K
+    from repro.kernels.conv2d.ops import SUBLANES
+
+    c, h, w = in_shape
+    kh, kw = spec.kernel
+    sy = spec.stride[0]
+    oh, ow = _conv_out_hw(h, w, spec)
+    cp = -(-c // SUBLANES) * SUBLANES
+    wp = w + 2 * spec.padding[1]
+    im2col = method in IM2COL_METHODS
+    ocb = (min(ADVANCED_OC_BLOCK, spec.out_channels) if im2col else
+           K.resolve_basic_ocb(spec.out_channels, ow, wp, cp, kh, kw, sy))
+    blk = K.resolve_oh_block(oh, ow, wp, cp, kh, kw, sy, ocb, oh_block,
+                             im2col=im2col)
+    return {
+        "kind": "conv", "blk": blk, "n_tiles": -(-oh // blk),
+        "total": oh, "band": K._band_rows(blk, kh, sy),
+        "row_step": blk * sy, "in_base": 0, "stride_eff": sy,
+        "carry": 0, "steps": -(-oh // blk),
+        "window_eff": kh, "padded_h": h + 2 * spec.padding[0],
+        "cell_bytes": K.conv_cell_bytes(blk, ow, wp, cp, kh, kw, sy,
+                                        ocb, im2col=im2col),
+        "floor_bytes": K.conv_cell_bytes(1, ow, wp, cp, kh, kw, sy,
+                                         ocb, im2col=im2col),
+        "budget": K.VMEM_BUDGET_BYTES, "out_hw": [oh, ow],
+    }
+
+
 def group_geometry(group: FusedLayerSpec, method: Method,
                    in_shape: Tuple[int, int, int],
                    oh_block: Optional[int], *,
@@ -571,6 +704,9 @@ def group_geometry(group: FusedLayerSpec, method: Method,
     geo = group_band_params(group, method, in_shape, oh_block,
                             pool_carry=pool_carry,
                             lrn_oc_block=lrn_oc_block)
-    return {"group": group.name, "convs": len(group.convs),
-            "rows_per_cell": geo["blk"], "n_tiles": geo["n_tiles"],
-            "out_hw": geo["out_hw"]}
+    row = {"group": group.name, "convs": len(group.convs),
+           "rows_per_cell": geo["blk"], "n_tiles": geo["n_tiles"],
+           "out_hw": geo["out_hw"]}
+    if group.shortcut is not None:
+        row["shortcut"] = group.shortcut
+    return row

@@ -1,21 +1,30 @@
 """The paper's three benchmark CNNs (Table 2): LeNet-5 (MNIST),
-Alex Krizhevsky's CIFAR-10 network, and AlexNet (ImageNet 2012)."""
+Alex Krizhevsky's CIFAR-10 network, and AlexNet (ImageNet 2012); and
+ResNet-50 v1.5, a branching network.
+
+A network is a graph: each layer reads the tensors its ``inputs`` name
+(earlier layers, or ``"input"`` for the frame), by default the layer
+before it, so a chain states none."""
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from dataclasses import dataclass
+from typing import Dict, List, Tuple
 
 
 @dataclass(frozen=True)
 class LayerSpec:
-    kind: str  # conv | pool | lrn | fc | relu | softmax | flatten
+    kind: str  # conv | pool | lrn | fc | relu | softmax | flatten | add
     name: str
     # conv/fc
     out_channels: int = 0
     kernel: Tuple[int, int] = (0, 0)
     stride: Tuple[int, int] = (1, 1)
-    padding: Tuple[int, int] = (0, 0)
-    relu: bool = False  # fused activation (paper §4.2)
+    padding: Tuple[int, int] = (0, 0)  # conv; pool: -inf max, 0 in avg
+    relu: bool = False  # fused activation (paper §4.2); add: after the sum
+    #: the layers read (``"input"``: the frame); empty: the layer before
+    inputs: Tuple[str, ...] = ()
+    #: conv/fc: the seeded weight draw is multiplied by it
+    gain: float = 1.0
     # pool
     pool_kind: str = "max"  # max | avg
     # lrn
@@ -111,4 +120,73 @@ def alexnet() -> NetworkDef:
     )
 
 
-NETWORKS = {"lenet5": lenet5, "cifar10": cifar10_quick, "alexnet": alexnet}
+def resnet50() -> NetworkDef:
+    """ResNet-50 v1.5 (torchvision ``resnet50``, MLPerf Inference's
+    classifier): the stride on each stage's first 3×3 conv, batch norm
+    folded into the convs' weights and biases.  Each bottleneck ``_a``
+    1×1 → ``_b`` 3×3 → ``_c`` 1×1 ends in ``_add``, the sum with the
+    block's input (or with its strided 1×1 projection ``_proj``, on each
+    stage's first block) and a ReLU.  ``gain=0.2`` on every ``_c`` keeps
+    seeded random logits near unit scale (with He draws alone their
+    second moment grows about 2.4× per block)."""
+    def conv(name, src, out, k, stride=1, relu=True, gain=1.0):
+        return LayerSpec("conv", name, out_channels=out, kernel=(k, k),
+                         stride=(stride, stride),
+                         padding=(k // 2, k // 2), relu=relu,
+                         inputs=(src,), gain=gain)
+
+    layers = [conv("conv1", "input", 64, 7, stride=2),
+              LayerSpec("pool", "pool1", kernel=(3, 3), stride=(2, 2),
+                        padding=(1, 1), inputs=("conv1",))]
+    x = "pool1"
+    for stage, (width, blocks) in enumerate(((64, 3), (128, 4), (256, 6),
+                                             (512, 3)), start=1):
+        for block in range(1, blocks + 1):
+            p = f"s{stage}b{block}_"
+            stride = 2 if stage > 1 and block == 1 else 1
+            layers += [conv(p + "a", x, width, 1),
+                       conv(p + "b", p + "a", width, 3, stride=stride),
+                       conv(p + "c", p + "b", 4 * width, 1, relu=False,
+                            gain=0.2)]
+            shortcut = x
+            if block == 1:
+                shortcut = p + "proj"
+                layers.append(conv(shortcut, x, 4 * width, 1,
+                                   stride=stride, relu=False))
+            layers.append(LayerSpec("add", p + "add", relu=True,
+                                    inputs=(p + "c", shortcut)))
+            x = p + "add"
+    layers += [
+        LayerSpec("pool", "pool5", kernel=(7, 7), stride=(1, 1),
+                  pool_kind="avg", inputs=(x,)),
+        LayerSpec("flatten", "flatten", inputs=("pool5",)),
+        LayerSpec("fc", "fc1000", out_channels=1000, inputs=("flatten",)),
+        LayerSpec("softmax", "prob", inputs=("fc1000",)),
+    ]
+    return NetworkDef(name="resnet50", input_shape=(3, 224, 224),
+                      num_classes=1000, layers=tuple(layers))
+
+
+def layer_inputs(net: NetworkDef) -> Dict[str, Tuple[str, ...]]:
+    """Each layer's inputs, resolved: its own ``inputs``, else the layer
+    before it (``"input"`` for the first)."""
+    out, prev = {}, "input"
+    for spec in net.layers:
+        out[spec.name] = spec.inputs or (prev,)
+        prev = spec.name
+    return out
+
+
+def layer_readers(net: NetworkDef) -> Dict[str, List[str]]:
+    """The layers that read each tensor (``"input"`` and layer names),
+    in network order; the last layer's output is read by the caller and
+    counts no reader here."""
+    readers: Dict[str, List[str]] = {}
+    for name, ins in layer_inputs(net).items():
+        for src in ins:
+            readers.setdefault(src, []).append(name)
+    return readers
+
+
+NETWORKS = {"lenet5": lenet5, "cifar10": cifar10_quick, "alexnet": alexnet,
+            "resnet50": resnet50}

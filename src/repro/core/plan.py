@@ -41,9 +41,8 @@ from repro.core.fusion import (
     CostGate,
     FusedLayerSpec,
     PlanItem,
-    _conv_out_hw,
-    _pool_out_hw,
     group_geometry,
+    layer_shapes,
     plan_fusion,
 )
 from repro.core.methods import (
@@ -53,35 +52,37 @@ from repro.core.methods import (
     conv2d_pool_fused,
     fc_fused,
     fc_seq_ref,
+    pallas_method_name,
 )
-from repro.core.netdefs import LayerSpec, NetworkDef
+from repro.core.netdefs import (
+    LayerSpec,
+    NetworkDef,
+    layer_inputs,
+    layer_readers,
+)
 
 Shape = Tuple[int, ...]
 
 
 def infer_param_shapes(net: NetworkDef) -> Dict[str, Tuple]:
-    """Propagate shapes through the net to size conv/fc parameters
-    (conv: OIHW weight shape; fc: ``(d_in, d_out)``).  An fc straight
-    after a conv/pool (no flatten layer) consumes the WHOLE ``c*h*w``
-    activation, not just the channel count."""
-    c, h, w = net.input_shape
-    shapes: Dict[str, Tuple] = {}
-    flat: Optional[int] = None
+    """Propagate shapes through the net's graph to size conv/fc
+    parameters (conv: OIHW weight shape; fc: ``(d_in, d_out)``).  An fc
+    fed a spatial activation (no flatten layer) consumes the WHOLE
+    ``c*h*w`` activation, not just the channel count."""
+    shapes = layer_shapes(net)
+    inputs = layer_inputs(net)
+    params: Dict[str, Tuple] = {}
     for spec in net.layers:
+        src = shapes[inputs[spec.name][0]]
         if spec.kind == "conv":
             kh, kw = spec.kernel
-            shapes[spec.name] = (spec.out_channels, c, kh, kw)
-            h, w = _conv_out_hw(h, w, spec)
-            c = spec.out_channels
-        elif spec.kind == "pool":
-            h, w = _pool_out_hw(h, w, spec)
-        elif spec.kind == "flatten":
-            flat = c * h * w
+            params[spec.name] = (spec.out_channels, src[0], kh, kw)
         elif spec.kind == "fc":
-            d_in = flat if flat is not None else c * h * w
-            shapes[spec.name] = (d_in, spec.out_channels)
-            flat = spec.out_channels
-    return shapes
+            d_in = 1
+            for d in src:
+                d_in *= int(d)
+            params[spec.name] = (d_in, spec.out_channels)
+    return params
 
 
 #: the conv methods worth sweeping per layer: the three fusable SIMD
@@ -120,10 +121,10 @@ def knob_space(net: NetworkDef, *,
     the other pointwise tail layers expose no tunable axis today.
     """
     space: Dict[str, Dict[str, list]] = {}
-    c, h, w = net.input_shape
+    shapes = layer_shapes(net)
     for spec in net.layers:
         if spec.kind == "conv":
-            oh, ow = _conv_out_hw(h, w, spec)
+            oh = shapes[spec.name][1]
             space[spec.name] = {
                 "methods": list(methods),
                 "oh_blocks": [None] + [b for b in oh_blocks if b < oh],
@@ -132,11 +133,7 @@ def knob_space(net: NetworkDef, *,
                 "lrn_oc_block": [None, True, False],
                 "oc_block_final": [None, 4, 8],
             }
-            c, h, w = spec.out_channels, oh, ow
-        elif spec.kind == "pool":
-            space[spec.name] = {"fuse": [True, False]}
-            h, w = _pool_out_hw(h, w, spec)
-        elif spec.kind == "lrn":
+        elif spec.kind in ("pool", "lrn"):
             space[spec.name] = {"fuse": [True, False]}
     return space
 
@@ -145,14 +142,20 @@ def knob_space(net: NetworkDef, *,
 class PlanStep:
     """One resolved executor step.  ``kind`` selects the executor:
     conv | fused (single conv + pool epilogue) | chain (multi-conv) |
-    pool | lrn | flatten | fc | relu | softmax.  ``names`` are the
-    original layer names the step covers (folded standalone ReLUs
-    included) — ``execute(collect=...)`` records the step's output under
-    every one of them, matching the per-layer interpreter."""
+    residual (single conv + add epilogue) | pool | lrn | flatten | fc |
+    relu | add | softmax.  ``names`` are the original layer names the
+    step covers (folded standalone ReLUs included) —
+    ``execute(collect=...)`` records the step's output under every one
+    of them, matching the per-layer interpreter.  ``inputs`` are the
+    tensors the step reads (``"input"``: the frame; a residual step's
+    shortcut last) and ``output`` the one it writes, by name;
+    ``in_shape`` is the first input's shape."""
     kind: str
     names: Tuple[str, ...]
     in_shape: Shape
     out_shape: Shape
+    inputs: Tuple[str, ...]
+    output: str
     spec: Optional[LayerSpec] = None          # per-layer steps
     group: Optional[FusedLayerSpec] = None    # fused / chain steps
     method: Optional[Method] = None           # conv / fc / fused / chain
@@ -184,18 +187,19 @@ def _lrn_kwargs(lrn: Optional[LayerSpec]) -> Dict:
 
 
 def _pool(x, spec: LayerSpec, use_pallas: bool = False, relu: bool = False):
-    """VALID pooling; ``relu`` is the folded standalone activation (applied
-    on top of the spec's own)."""
+    """Pooling over the frame padded by ``spec.padding`` (-inf for max,
+    zeros counted in the divisor for avg); ``relu`` is the folded
+    standalone activation (applied on top of the spec's own)."""
     do_relu = spec.relu or relu
     if use_pallas:
         from repro.kernels.pool2d import ops as pool_ops
 
         return pool_ops.pool2d(x, spec.kernel, spec.stride, spec.pool_kind,
-                               relu=do_relu)
+                               relu=do_relu, padding=spec.padding)
     from repro.kernels.pool2d.ref import pool2d_ref
 
     return pool2d_ref(x, spec.kernel, spec.stride, spec.pool_kind,
-                      relu=do_relu)
+                      relu=do_relu, padding=spec.padding)
 
 
 def _lrn(x, spec: LayerSpec):
@@ -247,6 +251,25 @@ def _exec_chain(plan: "ExecutionPlan", step: PlanStep, params, x):
         oh_block=step.oh_block, **step.kwargs)
 
 
+def _exec_residual(plan: "ExecutionPlan", step: PlanStep, params, x,
+                   shortcut):
+    # relu?(conv + b + shortcut): the single-conv cell's add epilogue
+    from repro.kernels.conv2d import ops as conv_ops
+
+    cv = step.group.conv
+    p = params[cv.name]
+    return conv_ops.conv2d_residual(
+        x, p["w"], p["b"], shortcut, cv.stride, cv.padding, step.relu,
+        method=pallas_method_name(step.method, "a residual join"),
+        oh_block=step.oh_block)
+
+
+def _exec_add(plan, step, params, x, *rest):
+    for y in rest:
+        x = x + y
+    return jnp.maximum(x, 0.0) if step.relu else x
+
+
 def _exec_pool(plan, step, params, x):
     return _pool(x, step.spec, plan.use_pallas, relu=step.relu)
 
@@ -280,11 +303,13 @@ _EXECUTORS: Dict[str, Callable] = {
     "conv": _exec_conv,
     "fused": _exec_fused,
     "chain": _exec_chain,
+    "residual": _exec_residual,
     "pool": _exec_pool,
     "lrn": _exec_lrn,
     "flatten": _exec_flatten,
     "fc": _exec_fc,
     "relu": _exec_relu,
+    "add": _exec_add,
     "softmax": _exec_softmax,
 }
 
@@ -314,11 +339,19 @@ class ExecutionPlan:
     def execute(self, params, x, collect: Optional[dict] = None):
         """x: [N, C, H, W].  A thin loop over the step executors — every
         fusion/folding/shape decision was resolved at compile time.  Each
-        step traces inside ``jax.named_scope(step.label)``, which costs
-        nothing at run time."""
-        for step in self.steps:
+        step reads its inputs by name and traces inside
+        ``jax.named_scope(step.label)``, which costs nothing at run time;
+        a tensor is dropped after its last reader."""
+        last = {n: i for i, s in enumerate(self.steps) for n in s.inputs}
+        live = {"input": x}
+        for i, step in enumerate(self.steps):
+            args = [live[n] for n in step.inputs]
             with jax.named_scope(step.label):
-                x = _EXECUTORS[step.kind](self, step, params, x)
+                x = _EXECUTORS[step.kind](self, step, params, *args)
+            for n in step.inputs:
+                if last[n] == i:
+                    live.pop(n, None)
+            live[step.output] = x
             if collect is not None:
                 for n in step.names:
                     collect[n] = x
@@ -327,12 +360,14 @@ class ExecutionPlan:
     def fusion_report(self) -> List[dict]:
         """Executed geometry of every fused group, read straight off the
         plan steps (each already carries its resolved input shape, method
-        and band override) — see ``fusion.group_geometry``."""
+        and band override) — see ``fusion.group_geometry``; a residual
+        group's row names its shortcut tensor."""
         return [group_geometry(
                     s.group, s.method, s.in_shape, s.oh_block,
                     pool_carry=(s.kwargs or {}).get("pool_carry"),
                     lrn_oc_block=(s.kwargs or {}).get("lrn_oc_block"))
-                for s in self.steps if s.kind in ("fused", "chain")]
+                for s in self.steps
+                if s.kind in ("fused", "chain", "residual")]
 
     def cost(self, model=None, batch: int = 1):
         """Modelled cost of this plan: a ``repro.core.cost.PlanCost``
@@ -363,11 +398,12 @@ def compile_plan(net: NetworkDef, *,
     """Lower ``net`` into an ``ExecutionPlan``.
 
     Subsumes the legacy interpreter's per-call work: runs the fusion
-    planner (``fuse=True``; the VMEM working-set check binds on the
-    Pallas path only), folds standalone ReLUs into the preceding
-    conv/fc/pool step (``fuse_relu``), resolves every layer's method /
-    ``oh_block`` override, and propagates activation shapes so each step
-    carries its input/output geometry.
+    planner (``fuse=True``; the VMEM working-set check and the residual
+    joins bind on the Pallas path only), folds standalone ReLUs into the
+    preceding conv/fc/pool step (``fuse_relu``) when they are its one
+    reader, resolves every layer's method / ``oh_block`` override, and
+    resolves the graph so each step carries the names it reads and
+    writes and its input/output geometry.
 
     ``cost_gate`` (see ``fusion.plan_fusion``) swaps the fusion
     planner's raw VMEM budget check for a cost-model admission decision
@@ -405,27 +441,24 @@ def compile_plan(net: NetworkDef, *,
         items: List[PlanItem] = plan_fusion(
             net, method_for=method_for, no_fuse=no, fuse_relu=fuse_relu,
             vmem_budget=vmem_budget, vmem_check=use_pallas,
-            cost_gate=cost_gate)
+            cost_gate=cost_gate, residual=use_pallas)
     else:
         items = list(net.layers)
 
+    shapes = layer_shapes(net)
+    inputs = layer_inputs(net)
+    readers = layer_readers(net)
     steps: List[PlanStep] = []
     final_items: List[PlanItem] = []
-    c, h, w = net.input_shape
-    cur: Shape = (c, h, w)
-    flat: Optional[int] = None
     for it in items:
         if isinstance(it, FusedLayerSpec):
-            in_shape = cur
-            c, h, w = cur
-            for cv in it.convs:
-                h, w = _conv_out_hw(h, w, cv)
-            c = it.convs[-1].out_channels
-            if it.pool is not None:
-                h, w = _pool_out_hw(h, w, it.pool)
-            cur = (c, h, w)
+            ins = inputs[it.conv.name]
             kw = _lrn_kwargs(it.lrn)
-            if len(it.convs) > 1:
+            if it.add is not None:
+                kind, kw = "residual", None
+                ins += (it.shortcut,)
+            elif len(it.convs) > 1:
+                kind = "chain"
                 # explicit final-stage oc block (keyed by the LAST conv —
                 # the chain cell's band lives in final-stage rows too)
                 # overrides the planner's admission-ladder choice; an LRN
@@ -435,69 +468,54 @@ def compile_plan(net: NetworkDef, *,
                     it = replace(it, oc_block_final=obf)
                 kw["oc_block_final"] = it.oc_block_final
             else:
+                kind = "fused"
                 kw["pool_carry"] = per_layer_pool_carry.get(it.conv.name)
                 kw["lrn_oc_block"] = per_layer_lrn_oc_block.get(it.conv.name)
             # a chain cell's band is defined in FINAL-stage rows, so the
             # last conv's oh_block override is the one that maps onto it
             steps.append(PlanStep(
-                kind="chain" if len(it.convs) > 1 else "fused",
-                names=it.names, in_shape=in_shape, out_shape=cur, group=it,
+                kind, it.names, shapes[ins[0]], shapes[it.names[-1]],
+                ins, it.names[-1], group=it,
                 method=method_for(it.conv.name),
                 oh_block=ohb_for(it.convs[-1].name),
-                kwargs=kw))
+                relu=it.add is not None and it.add.relu, kwargs=kw))
             final_items.append(it)
             continue
         spec = it
         final_items.append(spec)
-        in_shape = cur
+        ins = inputs[spec.name]
+        in_shape, out_shape = shapes[ins[0]], shapes[spec.name]
+        step = PlanStep(spec.kind, (spec.name,), in_shape, out_shape, ins,
+                        spec.name, spec=spec)
         if spec.kind == "conv":
-            c, h, w = cur
-            h, w = _conv_out_hw(h, w, spec)
-            c = spec.out_channels
-            cur = (c, h, w)
-            steps.append(PlanStep(
-                "conv", (spec.name,), in_shape, cur, spec=spec,
-                method=method_for(spec.name), oh_block=ohb_for(spec.name),
-                relu=spec.relu))
-        elif spec.kind == "pool":
-            c, h, w = cur
-            h, w = _pool_out_hw(h, w, spec)
-            cur = (c, h, w)
-            steps.append(PlanStep("pool", (spec.name,), in_shape, cur,
-                                  spec=spec, relu=spec.relu))
-        elif spec.kind == "lrn":
-            steps.append(PlanStep("lrn", (spec.name,), in_shape, cur,
-                                  spec=spec))
-        elif spec.kind == "flatten":
-            flat = int(cur[0] * cur[1] * cur[2]) if len(cur) == 3 else cur[0]
-            cur = (flat,)
-            steps.append(PlanStep("flatten", (spec.name,), in_shape, cur,
-                                  spec=spec))
+            step = replace(step, method=method_for(spec.name),
+                           oh_block=ohb_for(spec.name), relu=spec.relu)
+        elif spec.kind in ("pool", "add"):
+            step = replace(step, relu=spec.relu)
         elif spec.kind == "fc":
-            d_in = flat if flat is not None else int(cur[0] * cur[1] * cur[2])
-            flat = spec.out_channels
-            pre_flatten = len(cur) == 3
-            cur = (spec.out_channels,)
-            steps.append(PlanStep(
-                "fc", (spec.name,), in_shape, cur, spec=spec,
-                method=method_for(spec.name), relu=spec.relu,
-                pre_flatten=pre_flatten, d_in=d_in))
+            d_in = 1
+            for d in in_shape:
+                d_in *= int(d)
+            step = replace(step, method=method_for(spec.name),
+                           relu=spec.relu, pre_flatten=len(in_shape) == 3,
+                           d_in=d_in)
         elif spec.kind == "relu":
             # standalone-ReLU folding, resolved HERE not at trace time: a
-            # relu following a conv/fc/pool step joins that step's
-            # epilogue (its name joins the step so collect still sees it)
-            if (fuse_relu and steps
-                    and steps[-1].kind in ("conv", "fc", "pool")):
-                steps[-1] = replace(steps[-1], relu=True,
-                                    names=steps[-1].names + (spec.name,))
-            else:
-                steps.append(PlanStep("relu", (spec.name,), in_shape, cur,
-                                      spec=spec))
-        elif spec.kind == "softmax":
-            steps.append(PlanStep("softmax", (spec.name,), in_shape, cur,
-                                  spec=spec))
-        else:
+            # relu that alone reads a conv/fc/pool step's output joins
+            # that step's epilogue (its name joins the step so collect
+            # still sees it)
+            prev = steps[-1] if steps else None
+            if (fuse_relu and prev is not None
+                    and prev.kind in ("conv", "fc", "pool")
+                    and ins == (prev.output,)
+                    and readers.get(prev.output) == [spec.name]):
+                steps[-1] = replace(prev, relu=True,
+                                    names=prev.names + (spec.name,),
+                                    output=spec.name)
+                continue
+        elif spec.kind not in ("lrn", "flatten", "softmax"):
             raise ValueError(spec.kind)
+        steps.append(step)
     if use_pallas and jax.default_backend() == "tpu":
         refused = sorted({s.method.value for s in steps
                           if s.kind == "conv" and s.method in NO_TPU_KERNEL})

@@ -214,6 +214,19 @@ def auto_oh_block(oh, ow, wp, c, kh, kw, sy, oc_block,
     return 1
 
 
+def resolve_basic_ocb(oc, ow, wp, c, kh, kw, sy) -> int:
+    """The oc tile of an un-fused basic_simd cell: the full width (the
+    paper's §4.3 cell) when its one-row floor cell fits
+    ``VMEM_BUDGET_BYTES``, else halved while the half stays a whole
+    number of lane tiles, until the floor cell fits (ResNet-50's
+    1024→2048 projection: 8 MiB of weights at full width)."""
+    ocb = oc
+    while (conv_cell_bytes(1, ow, wp, c, kh, kw, sy, ocb, im2col=False)
+           > VMEM_BUDGET_BYTES and ocb % (2 * LANES) == 0):
+        ocb //= 2
+    return ocb
+
+
 def resolve_oh_block(oh, ow, wp, c, kh, kw, sy, oc_block, oh_block,
                      im2col: bool = True) -> int:
     """The output-row band a SIMD kernel will actually run with: the auto
@@ -579,11 +592,52 @@ def _pool_epilogue_halo(acc, o_ref, p_refs, pool, conv_relu, lrn):
 # ---------------------------------------------------------------------------
 
 
-def _basic_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
-                       sx, relu, pool=None, lrn=None):
+def _lane_tiled(xp, w, c, sy, sx):
+    """``(xp, w, c, cc)``: a padded NHWC input and its weights as a SIMD
+    cell reads them.  Mosaic loads with a stride only from a ref whose
+    minor dim is at most one lane tile, so a strided conv over more than
+    ``LANES`` channels views each input column as ``cc`` lane tiles side
+    by side, ``[N, HP, WP*cc, LANES]`` (tile ``k`` of column ``q`` at
+    ``q*cc + k``); channels are zero-padded to whole tiles, and ``w``
+    (the weights, input channels on axis -2) with them."""
+    if max(sy, sx) == 1 or c <= LANES:
+        return xp, w, c, 1
+    cc = -(-c // LANES)
+    if c % LANES:  # w: [..., C, OC], or flattened [KH*KW*C, OC]
+        xp = jnp.pad(xp, ((0, 0), (0, 0), (0, 0), (0, cc * LANES - c)))
+        w = jnp.pad(w.reshape(-1, c, w.shape[-1]),
+                    ((0, 0), (0, cc * LANES - c), (0, 0))
+                    ).reshape(*w.shape[:-2], -1, w.shape[-1])
+    n, hp, wp, _ = xp.shape
+    return xp.reshape(n, hp, wp * cc, LANES), w, cc * LANES, cc
+
+
+def _tap(x_ref, i, j, sy, sx, rows, ow, cc):
+    """Kernel tap ``(i, j)`` of an input-row band ref as a ``[rows*ow,
+    C]`` matrix, read from the lane-tiled view tile by tile when
+    ``cc > 1`` (see ``_lane_tiled``)."""
+    return jnp.concatenate(
+        [x_ref[pl.ds(i, rows, stride=sy), pl.ds(j * cc + k, ow,
+                                                 stride=sx * cc), :]
+         .reshape(rows * ow, -1) for k in range(cc)], axis=-1)
+
+
+def _split_refs(refs, residual):
+    """``(r_ref, o_ref, p_refs)`` of a SIMD cell's refs after its bias:
+    the shortcut's block where the cell runs a residual join (else
+    None), the output, and the pool epilogue's staging scratch."""
+    if residual:
+        return refs[0], refs[1], refs[2:]
+    return None, refs[0], refs[1:]
+
+
+def _basic_simd_kernel(x_ref, w_ref, b_ref, *refs, kh, kw, sy, sx, relu,
+                       pool=None, lrn=None, residual=False, cc=1):
     # x_ref: [BAND, WP, C] (input-row band); w_ref: [KH, KW, C, OC];
-    # o_ref: [OH_BLK, OW, OC] (unfused) or [PH_BLK, PW, OC] (fused pool,
-    # p_refs staging the conv band: pool_stage_shapes)
+    # refs: the shortcut's [OH_BLK, OW, OC] block (residual), then o_ref:
+    # [OH_BLK, OW, OC] (unfused) or [PH_BLK, PW, OC] (fused pool, the
+    # rest staging the conv band: pool_stage_shapes)
+    r_ref, o_ref, p_refs = _split_refs(refs, residual)
     if pool is None:
         ohh, oww, oc = o_ref.shape
     else:
@@ -593,8 +647,12 @@ def _basic_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
     acc = jnp.zeros((ohh * oww, oc), jnp.float32)
     for i in range(kh):
         for j in range(kw):
-            patch = x_ref[pl.ds(i, ohh, stride=sy), pl.ds(j, oww, stride=sx),
-                          :].reshape(ohh * oww, -1)  # [rows, C]: C on lanes
+            if cc == 1:  # [rows, C]: C on lanes
+                patch = x_ref[pl.ds(i, ohh, stride=sy),
+                              pl.ds(j, oww, stride=sx), :].reshape(
+                                  ohh * oww, -1)
+            else:
+                patch = _tap(x_ref, i, j, sy, sx, ohh, oww, cc)
             acc = acc + jnp.dot(
                 patch.astype(ACC_DTYPE),
                 w_ref[i, j].astype(ACC_DTYPE),
@@ -605,6 +663,8 @@ def _basic_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
     if pool is not None:  # fused super-layer: pool in VMEM, write pooled band
         _pool_epilogue(acc, o_ref, p_refs, pool, relu, lrn)
         return
+    if r_ref is not None:  # residual join: the shortcut before the ReLU
+        acc = acc + r_ref[...].reshape(ohh * oww, oc).astype(ACC_DTYPE)
     if relu:
         acc = jnp.maximum(acc, 0.0)
     o_ref[...] = acc.reshape(ohh, oww, oc).astype(o_ref.dtype)
@@ -614,7 +674,7 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
                       relu=False, oh_block=None, interpret: bool = False,
                       pool_kernel=None, pool_stride=None,
                       pool_kind: str = "max", pool_relu: bool = False,
-                      lrn=None):
+                      lrn=None, residual=None):
     n, h, wd, c = x_nhwc.shape
     kh, kw, _, oc = w_hwio.shape
     sy, sx = stride
@@ -623,6 +683,8 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
     oh, ow = _out_size(h, kh, sy, py), _out_size(wd, kw, sx, px)
     if lrn is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
+    if residual is not None and pool_kernel is not None:
+        raise ValueError("a residual epilogue runs without a pool")
     if pool_kernel is not None:
         # fused super-layer: each cell writes a pooled band, the conv
         # activation stays in VMEM
@@ -634,38 +696,51 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         pool = (pkh, pkw, psy, psx, pool_kind, pool_relu, ow)
         out_rows, out_cols = phb, pw
         scratch = pool_stage_shapes(cband, ow, oc)
+        ocb = oc
     else:
+        # an oc grid only where the full-width weights bust the budget
+        ocb = resolve_basic_ocb(oc, ow, wp, c, kh, kw, sy)
         bottom, ohb, n_tiles, band = _plan_oh_tiles(
-            (n, hp, wp, c), oh, kh, kw, sy, oh_block, ow, oc, im2col=False)
+            (n, hp, wp, c), oh, kh, kw, sy, oh_block, ow, ocb, im2col=False)
         pool = None
         row_step = ohb * sy
         out_rows, out_cols = ohb, ow
         scratch = []
     xp = jnp.pad(x_nhwc, ((0, 0), (py, py + bottom), (px, px), (0, 0)))
+    xp, w_hwio, c, cc = _lane_tiled(xp, w_hwio, c, sy, sx)
     kern = functools.partial(_basic_simd_kernel, kh=kh, kw=kw, sy=sy, sx=sx,
-                             relu=relu, pool=pool, lrn=lrn)
+                             relu=relu, pool=pool, lrn=lrn,
+                             residual=residual is not None, cc=cc)
+    in_specs = [
+        # element-offset indexing: bands overlap by the KH-sy halo rows
+        pl.BlockSpec((None, pl.Element(band), pl.Element(wp * cc),
+                      pl.Element(c // cc)),
+                     lambda i, t, u: (i, t * row_step, 0, 0)),
+        pl.BlockSpec((kh, kw, c, ocb), lambda i, t, u: (0, 0, 0, u)),
+        pl.BlockSpec((1, ocb), lambda i, t, u: (0, u)),
+    ]
+    operands = [xp, w_hwio, b.reshape(1, oc)]
+    if residual is not None:
+        # the shortcut, read block for block as the output is written
+        in_specs.append(pl.BlockSpec((None, out_rows, out_cols, ocb),
+                                     lambda i, t, u: (i, t, 0, u)))
+        operands.append(jnp.pad(
+            residual, ((0, 0), (0, n_tiles * out_rows - oh), (0, 0), (0, 0))))
     out = pl.pallas_call(
         kern,
-        grid=(n, n_tiles),
-        in_specs=[
-            # element-offset indexing: bands overlap by the KH-sy halo rows
-            pl.BlockSpec((None,
-                          pl.Element(band), pl.Element(wp), pl.Element(c)),
-                         lambda i, t: (i, t * row_step, 0, 0)),
-            pl.BlockSpec((kh, kw, c, oc), lambda i, t: (0, 0, 0, 0)),
-            pl.BlockSpec((1, oc), lambda i, t: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, out_rows, out_cols, oc),
-                               lambda i, t: (i, t, 0, 0)),
+        grid=(n, n_tiles, oc // ocb),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, out_rows, out_cols, ocb),
+                               lambda i, t, u: (i, t, 0, u)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * out_rows, out_cols, oc),
                                        x_nhwc.dtype),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-    )(xp, w_hwio, b.reshape(1, oc))
+    )(*operands)
     return out[:, :ph] if pool_kernel is not None else out[:, :oh]
 
 
@@ -674,32 +749,38 @@ def conv2d_basic_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
 # ---------------------------------------------------------------------------
 
 
-def _im2col_band(x_ref, kh, kw, sy, sx, rows, ow):
+def _im2col_band(x_ref, kh, kw, sy, sx, rows, ow, cc=1):
     """The [rows*ow, KH*KW*C] im2col patch matrix of an input-row band
     ref, one strided ``pl.ds`` load per kernel position (Mosaic lowers
-    strided ref loads, not strided slices of a loaded value)."""
+    strided ref loads, not strided slices of a loaded value) and lane
+    tile (``cc``, see ``_lane_tiled``)."""
     cols = []
     for i in range(kh):  # im2col built once per spatial tile, reused for
         for j in range(kw):  # the whole 128-wide output-channel block (§4.4)
-            cols.append(x_ref[pl.ds(i, rows, stride=sy),
-                              pl.ds(j, ow, stride=sx), :]
-                        .reshape(rows * ow, -1))
+            if cc == 1:
+                cols.append(x_ref[pl.ds(i, rows, stride=sy),
+                                  pl.ds(j, ow, stride=sx), :]
+                            .reshape(rows * ow, -1))
+            else:
+                cols.append(_tap(x_ref, i, j, sy, sx, rows, ow, cc))
     patches = jnp.concatenate(cols, axis=-1)  # [rows, KH*KW*C]
     return patches
 
 
-def _advanced_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
-                          sx, relu, pool=None, lrn=None):
+def _advanced_simd_kernel(x_ref, w_ref, b_ref, *refs, kh, kw, sy, sx, relu,
+                          pool=None, lrn=None, residual=False, cc=1):
     # x_ref: [BAND, WP, C] (input-row band); w_ref: [KH*KW*C, OC_BLK];
-    # o_ref: [OH_BLK, OW, OC_BLK] (unfused) or [PH_BLK, PW, OC_BLK] (fused,
-    # p_refs staging the conv band: pool_stage_shapes)
+    # refs: the shortcut's [OH_BLK, OW, OC_BLK] block (residual), then
+    # o_ref: [OH_BLK, OW, OC_BLK] (unfused) or [PH_BLK, PW, OC_BLK]
+    # (fused, the rest staging the conv band: pool_stage_shapes)
+    r_ref, o_ref, p_refs = _split_refs(refs, residual)
     if pool is None:
         ohh, oww, ocb = o_ref.shape
     else:
         pkh, _, psy, _, _, _, conv_ow = pool
         phh, _, ocb = o_ref.shape
         ohh, oww = (phh - 1) * psy + pkh, conv_ow  # conv rows this cell owns
-    patches = _im2col_band(x_ref, kh, kw, sy, sx, ohh, oww)
+    patches = _im2col_band(x_ref, kh, kw, sy, sx, ohh, oww, cc)
     acc = jnp.dot(patches.astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
                   preferred_element_type=jnp.float32,
                   precision=ACC_PRECISION)  # one MXU matmul
@@ -707,13 +788,15 @@ def _advanced_simd_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw, sy,
     if pool is not None:  # fused super-layer: pool in VMEM, write pooled band
         _pool_epilogue(acc, o_ref, p_refs, pool, relu, lrn)
         return
+    if r_ref is not None:  # residual join: the shortcut before the ReLU
+        acc = acc + r_ref[...].reshape(ohh * oww, ocb).astype(ACC_DTYPE)
     if relu:  # fused epilogue in VMEM — zero-cost ReLU (Fig. 5)
         acc = jnp.maximum(acc, 0.0)
     o_ref[...] = acc.reshape(ohh, oww, ocb).astype(o_ref.dtype)
 
 
 def _advanced_simd_halo_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw,
-                               sy, sx, relu, pool, lrn):
+                               sy, sx, relu, pool, lrn, cc=1):
     # two-pass channel-halo cell: w_ref/b_ref are widened to the tile's
     # ocb + n - 1 columns (its own channels plus the LRN window's
     # neighbours), so conv+pool run over the widened tile and
@@ -722,7 +805,7 @@ def _advanced_simd_halo_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw,
     pkh, _, psy, _, _, _, conv_ow = pool
     phh = o_ref.shape[0]
     ohh, oww = (phh - 1) * psy + pkh, conv_ow  # conv rows this cell owns
-    pmat = _im2col_band(x_ref, kh, kw, sy, sx, ohh, oww)
+    pmat = _im2col_band(x_ref, kh, kw, sy, sx, ohh, oww, cc)
     acc = jnp.dot(pmat.astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
                   preferred_element_type=jnp.float32,
                   precision=ACC_PRECISION)
@@ -731,7 +814,7 @@ def _advanced_simd_halo_kernel(x_ref, w_ref, b_ref, o_ref, *p_refs, kh, kw,
 
 
 def _advanced_simd_carry_kernel(x_ref, w_ref, b_ref, o_ref, c_ref, *p_refs,
-                                kh, kw, sy, sx, relu, pool, k_rows):
+                                kh, kw, sy, sx, relu, pool, k_rows, cc=1):
     # sliding-window pool accumulator: each band step convolves only its
     # R = PH_BLK*psy fresh conv rows; the K = pkh - psy boundary rows are
     # carried in VMEM scratch (c_ref) from the previous step instead of
@@ -742,7 +825,7 @@ def _advanced_simd_carry_kernel(x_ref, w_ref, b_ref, o_ref, c_ref, *p_refs,
     pkh, _, psy, _, _, _, conv_ow = pool
     phh, _, ocb = o_ref.shape
     r_rows = phh * psy  # fresh conv rows this step owns
-    pmat = _im2col_band(x_ref, kh, kw, sy, sx, r_rows, conv_ow)
+    pmat = _im2col_band(x_ref, kh, kw, sy, sx, r_rows, conv_ow, cc)
     acc = jnp.dot(pmat.astype(ACC_DTYPE), w_ref[...].astype(ACC_DTYPE),
                   preferred_element_type=jnp.float32,
                   precision=ACC_PRECISION)
@@ -794,7 +877,7 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
                          interpret: bool = False, pool_kernel=None,
                          pool_stride=None, pool_kind: str = "max",
                          pool_relu: bool = False, lrn=None, pool_carry=None,
-                         lrn_oc_block=None):
+                         lrn_oc_block=None, residual=None):
     n, h, wd, c = x_nhwc.shape
     kh, kw, _, oc = w_hwio.shape
     sy, sx = stride
@@ -803,6 +886,8 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
     oh, ow = _out_size(h, kh, sy, py), _out_size(wd, kw, sx, px)
     if lrn is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
+    if residual is not None and pool_kernel is not None:
+        raise ValueError("a residual epilogue runs without a pool")
     if pool_kernel is not None:
         pkh, pkw = pool_kernel
         psy, psx = pool_stride if pool_stride is not None else pool_kernel
@@ -874,19 +959,20 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         wp, c, kw, sx = ow, LANES, 1, 1
     else:
         xp = jnp.pad(x_nhwc, ((0, 0), rows, (px, px), (0, 0)))
+    xp, wmat, c, cc = _lane_tiled(xp, wmat, c, sy, sx)
     if carry:
         oc_tiles = ocp // ocb
         kern = functools.partial(_advanced_simd_carry_kernel, kh=kh, kw=kw,
                                  sy=sy, sx=sx, relu=relu, pool=pool,
-                                 k_rows=k_rows)
+                                 k_rows=k_rows, cc=cc)
         out = pl.pallas_call(
             kern,
             grid=(n, oc_tiles, n_tiles + 1),
             in_specs=[
                 # element-offset indexing; the carried rows replace the
                 # pool-window share of the inter-band halo
-                pl.BlockSpec((None,
-                              pl.Element(band), pl.Element(wp), pl.Element(c)),
+                pl.BlockSpec((None, pl.Element(band), pl.Element(wp * cc),
+                              pl.Element(c // cc)),
                              lambda i, u, j: (i, j * row_step, 0, 0)),
                 pl.BlockSpec((kh * kw * c, ocb), lambda i, u, j: (0, u)),
                 pl.BlockSpec((1, ocb), lambda i, u, j: (0, u)),
@@ -919,14 +1005,15 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
             [jax.lax.slice_in_dim(b, u * ocb, u * ocb + ocw, axis=1)
              .reshape(1, 1, ocw) for u in range(oc_tiles)], axis=0)
         kern = functools.partial(_advanced_simd_halo_kernel, kh=kh, kw=kw,
-                                 sy=sy, sx=sx, relu=relu, pool=pool, lrn=lrn)
+                                 sy=sy, sx=sx, relu=relu, pool=pool, lrn=lrn,
+                                 cc=cc)
         out = pl.pallas_call(
             kern,
             grid=(n, n_tiles, oc_tiles),
             in_specs=[
                 # element-offset indexing: bands overlap by the halo rows
-                pl.BlockSpec((None,
-                              pl.Element(band), pl.Element(wp), pl.Element(c)),
+                pl.BlockSpec((None, pl.Element(band), pl.Element(wp * cc),
+                              pl.Element(c // cc)),
                              lambda i, t, u: (i, t * row_step, 0, 0)),
                 pl.BlockSpec((None, kh * kw * c, ocw),
                              lambda i, t, u: (u, 0, 0)),
@@ -945,18 +1032,27 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
         )(xp, wmat, b)
         return out[:, :ph, :, :oc]
     kern = functools.partial(_advanced_simd_kernel, kh=kh, kw=kw, sy=sy,
-                             sx=sx, relu=relu, pool=pool, lrn=lrn)
+                             sx=sx, relu=relu, pool=pool, lrn=lrn,
+                             residual=residual is not None, cc=cc)
+    in_specs = [
+        # element-offset indexing: bands overlap by the KH-sy halo rows
+        pl.BlockSpec((None, pl.Element(band), pl.Element(wp * cc),
+                      pl.Element(c // cc)),
+                     lambda i, t, o: (i, t * row_step, 0, 0)),
+        pl.BlockSpec((kh * kw * c, ocb), lambda i, t, o: (0, o)),
+        pl.BlockSpec((1, ocb), lambda i, t, o: (0, o)),
+    ]
+    operands = [xp, wmat, b]
+    if residual is not None:
+        # the shortcut, read block for block as the output is written
+        in_specs.append(pl.BlockSpec((None, out_rows, out_cols, ocb),
+                                     lambda i, t, o: (i, t, 0, o)))
+        operands.append(jnp.pad(residual, (
+            (0, 0), (0, n_tiles * out_rows - oh), (0, 0), (0, pad_oc))))
     out = pl.pallas_call(
         kern,
         grid=(n, n_tiles, ocp // ocb),
-        in_specs=[
-            # element-offset indexing: bands overlap by the KH-sy halo rows
-            pl.BlockSpec((None,
-                          pl.Element(band), pl.Element(wp), pl.Element(c)),
-                         lambda i, t, o: (i, t * row_step, 0, 0)),
-            pl.BlockSpec((kh * kw * c, ocb), lambda i, t, o: (0, o)),
-            pl.BlockSpec((1, ocb), lambda i, t, o: (0, o)),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((None, out_rows, out_cols, ocb),
                                lambda i, t, o: (i, t, 0, o)),
         out_shape=jax.ShapeDtypeStruct((n, n_tiles * out_rows, out_cols, ocp),
@@ -967,7 +1063,7 @@ def conv2d_advanced_simd(x_nhwc, w_hwio, b, stride=(1, 1), padding=(0, 0),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-    )(xp, wmat, b)
+    )(*operands)
     if pool_kernel is not None:
         return out[:, :ph, :, :oc]
     return out[:, :oh, :, :oc]

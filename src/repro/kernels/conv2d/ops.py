@@ -70,29 +70,45 @@ def conv2d(x, w, b, stride=(1, 1), padding=(0, 0), relu=False,
     if lrn_n is not None and pool_kernel is None:
         raise ValueError("fused LRN epilogue requires a fused pool epilogue")
     lrn = (lrn_n, lrn_alpha, lrn_beta, lrn_k) if lrn_n is not None else None
-    # SIMD methods: channel padding + dimension swapping (§4.3); C is
-    # padded while it is a major axis, where the pad is cheap
+    return _simd(x, w, b, stride, padding, relu, method, oh_block, interp,
+                 pool_kernel=pool_kernel, pool_stride=pool_stride,
+                 pool_kind=pool_kind, pool_relu=pool_relu, lrn=lrn,
+                 pool_carry=pool_carry, lrn_oc_block=lrn_oc_block)
+
+
+@partial(jax.jit, static_argnames=("stride", "padding", "relu", "method",
+                                   "oh_block", "interpret"))
+def conv2d_residual(x, w, b, shortcut, stride=(1, 1), padding=(0, 0),
+                    relu=False, method: str = "advanced_simd_128",
+                    oh_block: int = None, interpret: bool = None):
+    """A residual join in one dispatch: ``conv(x) + b + shortcut``, then
+    ReLU if ``relu``, in fp32.  ``shortcut`` is ``[N, OC, OH, OW]``, the
+    conv's output shape; the SIMD cell reads it in the band and oc tile
+    of the output block it writes.  Its own name keeps its kernels
+    apart from ``conv2d``'s in a trace (``conv2d_residual.N``)."""
+    interp = interpret_default() if interpret is None else interpret
+    return _simd(x, w, b, stride, padding, relu, method, oh_block, interp,
+                 residual=nchw_to_nhwc(shortcut))
+
+
+def _simd(x, w, b, stride, padding, relu, method, oh_block, interp, **kw):
+    """A SIMD method's dispatch: channel padding + dimension swapping
+    (§4.3; C is padded while it is a major axis, where the pad is cheap),
+    the method's kernel with the epilogue ``kw`` name, and the swap
+    back."""
     xh = nchw_to_nhwc(pad_axis(x, 1, SUBLANES)[0])
     wh = oihw_to_hwio(w)
     wh, _ = pad_axis(wh, 2, SUBLANES)
     if method == "basic_simd":
+        kw.pop("pool_carry", None)
+        kw.pop("lrn_oc_block", None)
         out = K.conv2d_basic_simd(xh, wh, b, stride, padding, relu,
-                                  oh_block=oh_block, interpret=interp,
-                                  pool_kernel=pool_kernel,
-                                  pool_stride=pool_stride,
-                                  pool_kind=pool_kind, pool_relu=pool_relu,
-                                  lrn=lrn)
+                                  oh_block=oh_block, interpret=interp, **kw)
     elif method.startswith("advanced_simd"):
         # the paper's 4/8 outputs per thread, raised to the MXU lane tile
         out = K.conv2d_advanced_simd(xh, wh, b, stride, padding, relu,
                                      oc_block=K.LANES, oh_block=oh_block,
-                                     interpret=interp,
-                                     pool_kernel=pool_kernel,
-                                     pool_stride=pool_stride,
-                                     pool_kind=pool_kind,
-                                     pool_relu=pool_relu, lrn=lrn,
-                                     pool_carry=pool_carry,
-                                     lrn_oc_block=lrn_oc_block)
+                                     interpret=interp, **kw)
     else:
         raise ValueError(method)
     return nhwc_to_nchw(out)

@@ -10,6 +10,7 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.layout import nchw_to_nhwc, nhwc_to_nchw, pad_axis
 from repro.kernels.common import interpret_default
@@ -19,13 +20,19 @@ SUBLANES = 8  # channel padding multiple (mirrors conv2d.ops)
 
 
 @partial(jax.jit, static_argnames=("kernel", "stride", "kind", "relu",
-                                   "oh_block", "interpret"))
+                                   "oh_block", "interpret", "padding"))
 def pool2d(x, kernel=(2, 2), stride=(2, 2), kind: str = "max",
            relu: bool = False, oh_block: int = None,
-           interpret: bool = None):
-    """x: [N, C, H, W]; VALID window semantics."""
+           interpret: bool = None, padding=(0, 0)):
+    """x: [N, C, H, W]; VALID windows over the frame padded by
+    ``padding`` rows and columns on each side: -inf for max, zeros that
+    the avg divisor counts."""
     interp = interpret_default() if interpret is None else interpret
     xh = nchw_to_nhwc(x)
+    if padding != (0, 0):
+        py, px = padding
+        xh = jnp.pad(xh, ((0, 0), (py, py), (px, px), (0, 0)),
+                     constant_values=-jnp.inf if kind == "max" else 0.0)
     xh, orig_c = pad_axis(xh, 3, SUBLANES)  # pad value 0 never crosses
     out = K.pool2d_nhwc(xh, kernel, stride, kind, relu,  # channel lanes
                         oh_block=oh_block, interpret=interp)

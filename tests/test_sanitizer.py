@@ -75,10 +75,11 @@ def test_clean_full_sweep_grid():
     finally:
         sys.path.pop(0)
     findings, combos, dispatches = sanitize_cli.sweep()
-    # 3 nets x 3 methods x 2 fuse x 2 backends, plus the forced
-    # second-generation cell configs (carry / channel-halo LRN /
+    # every bundled net x 3 methods x 2 fuse x 2 backends, plus the
+    # forced second-generation cell configs (carry / channel-halo LRN /
     # oc-blocked chain final stage)
-    assert combos == 36 + len(sanitize_cli.EXTRA_CONFIGS)
+    assert combos == (12 * len(sanitize_cli.NETWORKS)
+                      + len(sanitize_cli.EXTRA_CONFIGS))
     assert dispatches > 100
     assert findings == []
 
@@ -106,8 +107,26 @@ def test_clean_single_dispatches():
                        paddings=[(1, 1), (1, 1)], relus=[True, True],
                        pool_kernel=(2, 2), pool_stride=(2, 2),
                        oh_block=4, oc_block_final=8),
+        # residual joins: several bands, and oc tiles of 128 over 160
+        # padded channels (advanced) or the full width (basic)
+        _sanitize_residual(),
+        sanitize_conv2d((2, 11, 9, 16), (1, 1, 16, 160), relu=True,
+                        im2col=False, oh_block=4, residual=True),
+        # a strided conv over two lane tiles of channels; basic_simd
+        # with its oc grid (full-width weights bust the budget)
+        sanitize_conv2d((2, 9, 10, 256), (3, 3, 256, 24), stride=(2, 2),
+                        padding=(1, 1), im2col=True, oh_block=2),
+        sanitize_conv2d((2, 14, 14, 1024), (1, 1, 1024, 2048),
+                        stride=(2, 2), im2col=False),
+        sanitize_pool2d((2, 114, 114, 64), kernel=(3, 3), stride=(2, 2)),
     ):
         assert f == []
+
+
+def _sanitize_residual(sources=None):
+    return sanitize_conv2d((2, 11, 9, 16), (1, 1, 16, 160), relu=True,
+                           im2col=True, oh_block=4, residual=True,
+                           sources=sources)
 
 
 # -- K101: out-of-bounds loads ----------------------------------------------
@@ -121,6 +140,18 @@ def test_k101_index_map_offset():
     f, _ = sanitize_conv2d((2, 28, 28, 8), (5, 5, 8, 16), im2col=True,
                            sources=sources)
     assert "K101" in _rules(f)
+
+
+def test_k101_residual_block_a_band_late():
+    """The shortcut's index map shifted by one band reads past the
+    shortcut for the last band: a spec-level K101, and nothing else."""
+    sources = _mutate("""in_specs.append(pl.BlockSpec((None, out_rows, out_cols, ocb),
+                                     lambda i, t, o: (i, t, 0, o)))""",
+                      """in_specs.append(pl.BlockSpec((None, out_rows, out_cols, ocb),
+                                     lambda i, t, o: (i, t + 1, 0, o)))""")
+    f, _ = _sanitize_residual(sources=sources)
+    assert _rules(f) == {"K101"}
+    assert any("in_spec 3" in x.detail for x in f)
 
 
 def test_k101_body_load():
@@ -138,8 +169,8 @@ def test_k101_body_load():
 
 def test_k102_grid_undercount():
     """Dropping one band tile leaves output rows never stored."""
-    sources = _mutate("grid=(n, n_tiles),", "grid=(n, n_tiles - 1),",
-                      count=1)  # first hit: conv2d_basic_simd
+    sources = _mutate("grid=(n, n_tiles, oc // ocb),",
+                      "grid=(n, n_tiles - 1, oc // ocb),")  # basic_simd
     f, _ = sanitize_conv2d((2, 28, 28, 8), (5, 5, 8, 16), padding=(2, 2),
                            im2col=False, oh_block=5, sources=sources)
     assert "K102" in _rules(f)
@@ -290,6 +321,16 @@ def test_phase_a_pool_carry_matches_resolver(pool_carry, pool, phb,
                 pool_carry, im2col, lrn, pool, phb, n_tiles) \
                 == K.resolve_pool_carry(pool_carry, im2col, lrn, pool,
                                         phb, n_tiles)
+
+
+@pytest.mark.parametrize("oc,c,kh", [(2048, 1024, 1), (512, 512, 3),
+                                     (256, 64, 1), (96, 3, 11), (384, 768, 3)])
+def test_phase_a_basic_ocb_matches_resolver(oc, c, kh):
+    from repro.kernels.conv2d import kernels as K
+
+    for ow, wp in ((7, 16), (56, 58)):
+        args = (oc, ow, wp, c, kh, kh, 1)
+        assert sanitizer._a_basic_ocb(*args) == K.resolve_basic_ocb(*args)
 
 
 @pytest.mark.parametrize("lrn_oc_block", [None, True, False])

@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.conv2d import ops as conv_ops
 from repro.kernels.matmul_fused import ops as mm_ops
+from repro.kernels.pool2d import ops as pool_ops
 
 BATCH = 16
 _LRN = dict(lrn_n=5, lrn_alpha=1e-4, lrn_beta=0.75, lrn_k=1.0)
@@ -56,6 +57,32 @@ def _lenet5_conv1(x, w, b):
                            pool_stride=(2, 2))
 
 
+def _resnet50_join(x, w, b, shortcut):
+    return conv_ops.conv2d_residual(x, w, b, shortcut, (1, 1), (0, 0), True,
+                                    method=_SIMD, interpret=False)
+
+
+def _resnet50_conv1(x, w, b):
+    return conv_ops.conv2d(x, w, b, (2, 2), (3, 3), True, method=_SIMD,
+                           interpret=False)
+
+
+def _resnet50_strided(pad):
+    def conv(x, w, b):
+        return conv_ops.conv2d(x, w, b, (2, 2), pad, pad != (0, 0),
+                               method=_SIMD, interpret=False)
+    return conv
+
+
+def _resnet50_pool1(x):
+    return pool_ops.pool2d(x, (3, 3), (2, 2), "max", padding=(1, 1),
+                           interpret=False)
+
+
+def _resnet50_pool5(x):
+    return pool_ops.pool2d(x, (7, 7), (1, 1), "avg", interpret=False)
+
+
 #: dispatch -> (function, operand shapes)
 CASES = {
     "alexnet-conv1+pool1+norm1": (
@@ -69,6 +96,25 @@ CASES = {
     "alexnet-fc6": (_alexnet_fc6, [(BATCH, 9216), (9216, 4096), (4096,)]),
     "lenet5-conv1+pool1": (
         _lenet5_conv1, [(BATCH, 1, 28, 28), (20, 1, 5, 5), (20,)]),
+    # ResNet-50: the residual joins at both ends of the network, the
+    # 224x224 stem's width-tap dispatch, the padded max pool, strided
+    # convs over 2 and 8 lane tiles of channels, the global pool
+    "resnet50-s1b1_c+s1b1_add": (
+        _resnet50_join, [(BATCH, 64, 56, 56), (256, 64, 1, 1), (256,),
+                         (BATCH, 256, 56, 56)]),
+    "resnet50-s4b3_c+s4b3_add": (
+        _resnet50_join, [(BATCH, 512, 7, 7), (2048, 512, 1, 1), (2048,),
+                         (BATCH, 2048, 7, 7)]),
+    "resnet50-conv1": (
+        _resnet50_conv1, [(BATCH, 3, 224, 224), (64, 3, 7, 7), (64,)]),
+    "resnet50-pool1": (_resnet50_pool1, [(BATCH, 64, 112, 112)]),
+    "resnet50-s3b1_b": (
+        _resnet50_strided((1, 1)), [(BATCH, 256, 28, 28), (256, 256, 3, 3),
+                                    (256,)]),
+    "resnet50-s4b1_proj": (
+        _resnet50_strided((0, 0)), [(BATCH, 1024, 14, 14),
+                                    (2048, 1024, 1, 1), (2048,)]),
+    "resnet50-pool5": (_resnet50_pool5, [(BATCH, 2048, 7, 7)]),
 }
 
 
@@ -143,7 +189,6 @@ def test_bucket_program_puts_each_kernel_under_its_plan_step(one_chip,
     names (``conv2d.3``, ``matmul_fused.2``) to their plan steps."""
     from repro.core.engine import CNNEngine
     from repro.core.netdefs import NETWORKS
-    from repro.kernels.pool2d import ops as pool_ops
 
     for mod in (conv_ops, mm_ops, pool_ops):
         monkeypatch.setattr(mod, "interpret_default", lambda: False)
@@ -160,3 +205,39 @@ def test_bucket_program_puts_each_kernel_under_its_plan_step(one_chip,
         "conv1+pool1", "conv2+pool2", "conv3+pool3", "fc1", "fc2"]
     assert steps["x.1"] == ""
     assert {s.label for s in eng.plan().steps} == set(steps.values()) - {""}
+
+
+def test_the_residual_join_is_its_own_kernel_in_the_program(one_chip):
+    fn, shapes = CASES["resnet50-s1b1_c+s1b1_add"]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(r"%conv2d_residual\.\d+ = \S+ custom-call\(", text)
+
+
+def test_resnet50_bucket_program_maps_its_joins_to_their_steps(
+        one_chip, monkeypatch):
+    """ResNet-50's whole bucket program at batch 16, compiled for v5e:
+    each residual join's kernel (``conv2d_residual.N``) carries its
+    step's scope, so ``op_steps`` names every join."""
+    from repro.core.engine import CNNEngine
+    from repro.core.netdefs import NETWORKS
+
+    for mod in (conv_ops, mm_ops, pool_ops):
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    eng = CNNEngine(NETWORKS["resnet50"](), use_pallas=True)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(eng.init, jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((BATCH, *eng.net.input_shape), jnp.float32,
+                             sharding=one_chip)
+    steps = eng.op_steps(params, x)
+    joins = sorted(label for n, label in steps.items()
+                   if n.startswith("conv2d_residual."))
+    want = sorted(s.label for s in eng.plan().steps if s.kind == "residual")
+    assert len(want) == 16 and joins == want
+    kernels = [n for n in steps if re.match(
+        r"(conv2d|conv2d_chain|conv2d_residual|pool2d|matmul_fused)\.\d+$",
+        n)]
+    assert len(kernels) == sum(s.kind not in ("flatten", "softmax")
+                               for s in eng.plan().steps)

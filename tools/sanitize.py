@@ -70,14 +70,15 @@ def sanitize_step(plan, step, label: str):
 
     Returns ``(findings, geom)``; ``(None, None)`` when the step has no
     banded Pallas dispatch under this config (reference methods, XLA
-    pool/fc legs, pointwise steps).  Operand shapes mirror the host-side
-    layout work of the ops wrappers: NCHW -> NHWC, channels padded to
-    the sublane multiple (chains also pad per-stage output channels).
+    pool/fc legs, pointwise and add steps).  Operand shapes mirror the
+    host-side layout work of the ops wrappers: NCHW -> NHWC, channels
+    padded to the sublane multiple (chains also pad per-stage output
+    channels).
     """
-    if step.kind == "conv":
+    if step.kind in ("conv", "residual"):
         if step.method not in _BANDED_METHODS:
             return None, None
-        spec = step.spec
+        spec = step.spec if step.kind == "conv" else step.group.conv
         c, h, w = step.in_shape
         cp = _ceil8(c)
         im2col = step.method in IM2COL_METHODS
@@ -88,7 +89,8 @@ def sanitize_step(plan, step, label: str):
             (BATCH, h, w, cp), (spec.kernel[0], spec.kernel[1], cp,
                                 spec.out_channels),
             stride=spec.stride, padding=spec.padding, relu=step.relu,
-            im2col=im2col, label=label, **kw_extra)
+            im2col=im2col, residual=step.kind == "residual",
+            oh_block=step.oh_block, label=label, **kw_extra)
     if step.kind == "fused":
         g = step.group
         cv = g.conv
@@ -132,8 +134,9 @@ def sanitize_step(plan, step, label: str):
     if step.kind == "pool" and plan.use_pallas:
         spec = step.spec
         c, h, w = step.in_shape
+        py, px = spec.padding  # the wrapper pads the frame first
         return sanitizer.sanitize_pool2d(
-            (BATCH, h, w, _ceil8(c)), kernel=spec.kernel,
+            (BATCH, h + 2 * py, w + 2 * px, _ceil8(c)), kernel=spec.kernel,
             stride=spec.stride, kind=spec.pool_kind,
             relu=spec.relu or step.relu, label=label)
     if (step.kind == "fc" and plan.use_pallas
